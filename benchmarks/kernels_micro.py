@@ -1,6 +1,6 @@
 """Kernel microbenchmarks: tuned-vs-default per-sweep timings of the DiFuseR
-kernels (ref implementations under XLA:CPU — on TPU the same harness times
-the Pallas kernels with interpret=False).
+kernels (the ref implementations; with ``impl="pallas"`` the Pallas kernels run
+interpreted on CPU and Mosaic-compiled on a TPU).
 
 The tunable sweep families (plus ``fused_sweep``, measured at the
 register-heavy scale-10/R=2048 shape where multi-sweep fusion and lane-fill
@@ -51,9 +51,10 @@ def main(scale: int = 12, registers: int = 512,
                             families=("fused_sweep",), cache=default_cache()))
     for family, rec in records.items():
         emit(f"kernel.{family}.default", rec["default_us"], "hard-coded")
+        roof = (f" ({rec['frac_of_roof']:.2%} of roof)"
+                if rec["frac_of_roof"] is not None else "")
         emit(f"kernel.{family}.tuned", rec["tuned_us"],
-             f"{rec['speedup']:.3g}x @ {rec['tuned_gbps']:.3g} GB/s "
-             f"({rec['frac_of_roof']:.2%} of roof)")
+             f"{rec['speedup']:.3g}x @ {rec['tuned_gbps']:.3g} GB/s{roof}")
 
     # the two untuned (vertex-dimension) kernels, timed as before
     m = ops.sketch_fill(jnp.zeros((g.n_pad, registers), jnp.int8), seed=3)

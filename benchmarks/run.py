@@ -16,6 +16,7 @@ import argparse
 import os
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -88,14 +89,17 @@ def main() -> None:
             print("tune.cache,0,reused baseline TUNE_cache.json")
 
     print("name,us_per_call,derived")
+    failed = []
     for name, job in jobs.items():
         if args.only and args.only not in name:
             continue
         t0 = time.time()
         try:
             job()
-        except Exception as e:  # noqa: BLE001 — report and continue
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail at exit
+            traceback.print_exc()
             print(f"{name}.ERROR,0,{type(e).__name__}: {e}", file=sys.stdout)
+            failed.append(name)
         print(f"{name}.total_s,{(time.time()-t0)*1e6:.0f},done")
 
     if recorder is not None:
@@ -124,6 +128,10 @@ def main() -> None:
                   f"{args.regression_threshold:.0%} vs {args.baseline_dir}",
                   file=sys.stderr)
             sys.exit(1)
+    if failed:
+        print(f"{len(failed)} job(s) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -53,10 +53,6 @@ def _serve_workload(engine, key, g, num_queries, k, seed):
 
 def _device_placement_ok(mu_v: int):
     """(ok, reason) for shard-local serving on this host."""
-    from repro.utils.jax_compat import JAX_HAS_AXIS_TYPE
-
-    if not JAX_HAS_AXIS_TYPE:
-        return False, "jax.sharding.AxisType missing (old jax)"
     import jax
 
     if len(jax.devices()) < mu_v:

@@ -20,7 +20,7 @@ from repro.core.fasst import build_partition
 from repro.core.sampling import make_x_vector
 from repro.graphs import rmat_graph
 from repro.partition import build_partition_2d, plan_partition, sample_edge_sets
-from repro.utils.roofline import HBM_BW, ICI_BW
+from repro.utils.roofline import V5E
 
 SWEEPS_PER_ROUND = 6  # measured propagate+cascade fixpoint sweeps (rmat graphs)
 
@@ -46,11 +46,11 @@ def main(scale: int = 11, registers: int = 1024, mu_v: int = 4, mu_s: int = 2,
         j_sim = registers // (mu_v * mu_s)
         sweep_bytes = (g.n_pad * j_sim                      # register matrix
                        + float(part_sim.edge_counts.max()) * j_sim * 3.0)
-        t_comp = SWEEPS_PER_ROUND * sweep_bytes / HBM_BW
-        sel = 2 * g.n_pad * 4 * 2 * (mu_v * mu_s - 1) / (mu_v * mu_s) / ICI_BW
+        t_comp = SWEEPS_PER_ROUND * sweep_bytes / V5E.hbm_bw
+        sel = 2 * g.n_pad * 4 * 2 * (mu_v * mu_s - 1) / (mu_v * mu_s) / V5E.ici_link_bw
         frac = sel / (t_comp + sel)
         emit(f"table9.sim_only.{setting}", 0.0,
-             f"comm={frac*100:.1f}% sel_B={sel*ICI_BW:.3g} (paper mode: 1.4-5.4%)")
+             f"comm={frac*100:.1f}% sel_B={sel*V5E.ici_link_bw:.3g} (paper mode: 1.4-5.4%)")
 
         # --- beyond-paper 2-D partition: ring traffic per sweep, from the
         # *built* partition (measured busiest shard + per-step pad overhead
@@ -76,12 +76,12 @@ def main(scale: int = 11, registers: int = 1024, mu_v: int = 4, mu_s: int = 2,
             padded_total = real_total / max(1.0 - stats.pad_waste_frac, 1e-9)
             padded_dev = padded_total / (mu_v * mu_s)
             sweep_bytes2 = n_loc * j_loc + padded_dev * j_loc * 3.0
-            t_comp2 = SWEEPS_PER_ROUND * sweep_bytes2 / HBM_BW
-            ring = SWEEPS_PER_ROUND * stats.ring_bytes_per_sweep / ICI_BW
-            sel2 = 2 * n_loc * 4 * 2 * (mu_s - 1) / mu_s / ICI_BW
+            t_comp2 = SWEEPS_PER_ROUND * sweep_bytes2 / V5E.hbm_bw
+            ring = SWEEPS_PER_ROUND * stats.ring_bytes_per_sweep / V5E.ici_link_bw
+            sel2 = 2 * n_loc * 4 * 2 * (mu_s - 1) / mu_s / V5E.ici_link_bw
             frac2 = (ring + sel2) / (t_comp2 + ring + sel2)
             emit(f"table9.ring2d.{strat}.{setting}", 0.0,
-                 f"comm={frac2*100:.1f}% ring_B={ring*ICI_BW:.3g} "
+                 f"comm={frac2*100:.1f}% ring_B={ring*V5E.ici_link_bw:.3g} "
                  f"edge_imb={stats.edge_imbalance:.2f} "
                  f"backend={backend_name} "
                  f"(2-D mode trades ring traffic for n beyond HBM; "

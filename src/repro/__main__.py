@@ -29,6 +29,10 @@ def main(argv=None) -> None:
               "run `python -m repro <command> --help` for per-command flags")
         raise SystemExit(0 if argv else 2)
     cmd, rest = argv[0], argv[1:]
+    if cmd in ("im", "serve"):
+        from repro.launch.common import enable_compile_cache
+
+        enable_compile_cache()
     if cmd == "im":
         from repro.launch.im import run
 

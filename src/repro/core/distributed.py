@@ -61,12 +61,6 @@ from repro.obs import shardprof, trace
 from repro.partition import (Partition2D, build_partition_2d,  # noqa: F401
                              plan_partition, sample_edge_sets)
 
-# jax API drift guard (single source: utils/jax_compat.py, re-exported here):
-# old containers ship a jax without jax.sharding.AxisType and its
-# mesh/shard_map surface. Tests that need a multi-device mesh skip on this
-# flag instead of erroring.
-from repro.utils.jax_compat import JAX_HAS_AXIS_TYPE  # noqa: F401
-
 # ---------------------------------------------------------------------------
 # Device-side shard_map body
 # ---------------------------------------------------------------------------

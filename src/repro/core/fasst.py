@@ -12,13 +12,18 @@ sorts X so that:
      which is simultaneously the load-balancing / straggler-mitigation
      mechanism (max shard size == straggler bound).
 
-All of this runs once on host (numpy) during setup; the device code only
-ever sees the resulting per-shard X slices and padded edge lists.
+All of this runs once during setup — on the host in numpy, except the
+(edges x samples) liveness test, which runs on the default device; the
+device code only ever sees the resulting per-shard X slices and padded
+edge lists.
 """
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sampling import edge_hash, weight_to_threshold
@@ -87,12 +92,25 @@ def _sampled_by_any(edge_h: np.ndarray, thr: np.ndarray, x_chunk: np.ndarray,
     if lo is None:
         lo = np.zeros_like(thr, dtype=np.uint32)
     m = edge_h.shape[0]
-    out = np.zeros(m, dtype=bool)
-    for a in range(0, m, chunk_edges):
-        b = min(a + chunk_edges, m)
-        out[a:b] = predicate(edge_h[a:b, None], lo[a:b, None], thr[a:b, None],
-                             x_chunk[None, :]).any(axis=1)
-    return out
+    chunk_edges = max(1, min(chunk_edges, m))
+    pad = (-m) % chunk_edges   # thr = 0 filler is never live
+    ops = [np.pad(np.asarray(a, np.uint32), (0, pad)).reshape(-1, chunk_edges)
+           for a in (edge_h, lo, thr)]
+    live = _live_any(*ops, jnp.asarray(x_chunk, jnp.uint32),
+                     predicate=predicate)
+    return np.asarray(live).reshape(-1)[:m]
+
+
+@partial(jax.jit, static_argnames=("predicate",))
+def _live_any(h, lo, thr, x, *, predicate):
+    """(chunks, chunk) edge operands -> bool (chunks, chunk): live under any
+    of ``x`` — one (chunk x len(x)) predicate tile at a time, on device."""
+    def chunk(ops):
+        hh, ll, tt = ops
+        return predicate(hh[:, None], ll[:, None], tt[:, None],
+                         x[None, :]).any(axis=1)
+
+    return jax.lax.map(chunk, (h, lo, thr))
 
 
 def build_partition(g: Graph, x: np.ndarray, mu: int, *, method: str = "fasst",

@@ -108,7 +108,7 @@ def remix_interval_predicate(h: Array, lo: Array, width: Array, x: Array) -> Arr
 
 def weight_to_threshold(w: np.ndarray) -> np.ndarray:
     """Map probability w in [0,1] to a uint32 compare threshold w * 2^32."""
-    thr = np.minimum(np.round(np.float64(w) * 4294967296.0), np.float64(UINT32_MAX))
+    thr = np.minimum(np.round(np.asarray(w, np.float64) * 4294967296.0), np.float64(UINT32_MAX))
     return thr.astype(np.uint32)
 
 

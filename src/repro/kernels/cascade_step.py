@@ -9,10 +9,11 @@ a dense sweep over the (dst-sorted) edge list whose per-lane work is a
 compare + select. The fixpoint driver (core/cascade.py) supplies the early
 exit the queue provided: it stops as soon as a sweep changes nothing.
 
-Same schedule as sketch_propagate (register tile major, edge blocks minor,
-register panes VMEM-resident); Jacobi semantics, bit-exact vs ref.py.
-Same diffusion-model hook as sketch_propagate: per-edge (h, lo) operands
-plus a static ``predicate`` (default: the universal interval form).
+Same schedule, SMEM edge table, aligned int8 row access and VMEM bound as
+sketch_propagate (register tile major, edge blocks minor, register panes
+VMEM-resident); Jacobi semantics, bit-exact vs ref.py. Same diffusion-model
+hook as sketch_propagate: per-edge (h, lo) operands plus a static
+``predicate`` (default: the universal interval form).
 """
 from __future__ import annotations
 
@@ -22,75 +23,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.sampling import edge_hash, fused_predicate
-from repro.kernels.common import EDGE_BLOCK, REG_TILE, clamp_block
-from repro.kernels.sketch_propagate import (pad_edge_operands,
-                                            pad_register_axis)
-
-VISITED = -1  # python literal: weak-typed inside kernels (no captured consts)
+from repro.kernels.common import (EDGE_BLOCK, REG_TILE, VISITED, edge_mask,
+                                  read_row, update_row)
+from repro.kernels.ref import edge_args
+from repro.kernels.sketch_propagate import DST, HLT, SRC, edge_sweep_call
 
 
-def _cascade_kernel(src_ref, dst_ref, h_ref, lo_ref, thr_ref, x_ref, m_ref,
-                    out_ref, *, edge_block: int, predicate):
-    eb = pl.program_id(1)
-
-    @pl.when(eb == 0)
+def _cascade_kernel(tab_ref, x_ref, m_ref, out_ref, *, edge_block: int,
+                    predicate):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = m_ref[...]
 
-    src = src_ref[...]
-    dst = dst_ref[...]
-    h = h_ref[...].astype(jnp.uint32)
-    lo = lo_ref[...].astype(jnp.uint32)
-    thr = thr_ref[...].astype(jnp.uint32)
-    x = x_ref[...].astype(jnp.uint32)
+    x = x_ref[...]
 
-    def body(i, _):
-        u = src[i]
-        v = dst[i]
-        mask = predicate(h[i], lo[i], thr[i], x)
-        vis_u = pl.load(m_ref, (u, slice(None))) == VISITED  # Jacobi read
+    def body(i, carry):
+        mask = edge_mask(tab_ref, i, HLT, x, predicate)
+        vis_u = read_row(m_ref, tab_ref[SRC, i]) == VISITED  # Jacobi read
         newly = jnp.logical_and(mask, vis_u)
-        cur = pl.load(out_ref, (v, slice(None)))
-        pl.store(out_ref, (v, slice(None)), jnp.where(newly, jnp.full_like(cur, VISITED), cur))
-        return 0
+        update_row(out_ref, tab_ref[DST, i],
+                   lambda cur: jnp.where(newly, VISITED, cur))
+        return carry
 
     jax.lax.fori_loop(0, edge_block, body, 0)
 
 
-@partial(jax.jit, static_argnames=("seed", "edge_block", "reg_tile", "interpret",
+@partial(jax.jit, static_argnames=("seed", "edge_block", "reg_tile",
                                    "predicate"))
 def cascade_sweep_pallas(m, src, dst, thr, x, h=None, lo=None, *, seed: int = 0,
                          edge_block: int = EDGE_BLOCK, reg_tile: int = REG_TILE,
-                         interpret: bool = True, predicate=None):
-    if h is None:
-        h = edge_hash(src, dst, seed=seed)
-    if lo is None:
-        lo = jnp.zeros(thr.shape, jnp.uint32)
-    if predicate is None:
-        predicate = fused_predicate
-    n_pad, num_regs = m.shape
-    num_edges = src.shape[0]
-    reg_tile = clamp_block(num_regs, reg_tile)
-    edge_block = clamp_block(num_edges, edge_block)
-    src, dst, h, lo, thr = pad_edge_operands(src, dst, h, lo, thr, edge_block)
-    m_in, x = pad_register_axis(m, x, reg_tile)
-    regs_pad = x.shape[0]
-    grid = (regs_pad // reg_tile, src.shape[0] // edge_block)
-    out = pl.pallas_call(
-        partial(_cascade_kernel, edge_block=edge_block, predicate=predicate),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((reg_tile,), lambda r, e: (r,)),
-            pl.BlockSpec((n_pad, reg_tile), lambda r, e: (0, r)),
-        ],
-        out_specs=pl.BlockSpec((n_pad, reg_tile), lambda r, e: (0, r)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, regs_pad), jnp.int8),
-        interpret=interpret,
-    )(src, dst, h, lo, thr, x, m_in)
-    return out[:, :num_regs] if regs_pad != num_regs else out
+                         predicate=None):
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
+    return edge_sweep_call(_cascade_kernel, "cascade_sweep", m, src, dst, thr,
+                           x, h, lo, edge_block=edge_block, reg_tile=reg_tile,
+                           predicate=predicate)

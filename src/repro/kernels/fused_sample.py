@@ -9,11 +9,11 @@ lane dimension, edges ride sublanes. No MXU, no reductions, no control flow.
 
 TPU tiling:
   grid = (E / EDGE_BLOCK, R / REG_TILE)
-  VMEM per step: per-edge operands 3 x EDGE_BLOCK x 4 B (h/lo/thr — src/dst
-  are consumed host-side by the hash precompute and never enter the kernel),
-  x REG_TILE x 4 B, out EDGE_BLOCK x REG_TILE x 1 B  ->  ~71 KiB at
-  (512, 128): trivially VMEM-resident; the grid is compute-bound on the VPU
-  as intended.
+  VMEM per step: the per-edge operands as one (EDGE_BLOCK, 3) int32 column
+  block (h/lo/thr — src/dst are consumed by the hash precompute and never
+  enter the kernel), x as a (1, REG_TILE) row, out EDGE_BLOCK x REG_TILE x
+  1 B: trivially VMEM-resident; the grid is compute-bound on the VPU as
+  intended.
 """
 from __future__ import annotations
 
@@ -23,53 +23,40 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.sampling import edge_hash, fused_predicate
-from repro.kernels.common import EDGE_BLOCK, REG_TILE, clamp_block
-from repro.kernels.sketch_propagate import (pad_edge_operands,
-                                            pad_register_axis)
+from repro.kernels.common import (EDGE_BLOCK, REG_TILE, clamp_block,
+                                  edge_block_for, edge_table, pallas_call)
+from repro.kernels.ref import edge_args
+from repro.kernels.sketch_propagate import pad_register_axis
 
 
-def _fused_sample_kernel(h_ref, lo_ref, thr_ref, x_ref, out_ref, *, predicate):
-    h = h_ref[...].astype(jnp.uint32)
-    lo = lo_ref[...].astype(jnp.uint32)
-    thr = thr_ref[...].astype(jnp.uint32)
-    x = x_ref[...].astype(jnp.uint32)
-    mask = predicate(h[:, None], lo[:, None], thr[:, None], x[None, :])
+def _fused_sample_kernel(tab_ref, x_ref, out_ref, *, predicate):
+    h, lo, thr = (jax.lax.bitcast_convert_type(tab_ref[:, c:c + 1], jnp.uint32)
+                  for c in range(3))
+    mask = predicate(h, lo, thr, x_ref[...])
     out_ref[...] = mask.astype(jnp.uint8)
 
 
-@partial(jax.jit, static_argnames=("seed", "edge_block", "reg_tile", "interpret",
+@partial(jax.jit, static_argnames=("seed", "edge_block", "reg_tile",
                                    "predicate"))
 def fused_sample_pallas(src, dst, thr, x, h=None, lo=None, *, seed: int = 0,
                         edge_block: int = EDGE_BLOCK, reg_tile: int = REG_TILE,
-                        interpret: bool = True, predicate=None):
-    if h is None:
-        h = edge_hash(src, dst, seed=seed)
-    if lo is None:
-        lo = jnp.zeros(thr.shape, jnp.uint32)
-    if predicate is None:
-        predicate = fused_predicate
+                        predicate=None):
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
     num_edges = src.shape[0]
     num_regs = x.shape[0]
-    edge_block = clamp_block(num_edges, edge_block)
+    eb = edge_block_for(num_edges, edge_block)
     reg_tile = clamp_block(num_regs, reg_tile)
-    src, dst, h, lo, thr = pad_edge_operands(src, dst, h, lo, thr, edge_block)
-    _, x = pad_register_axis(None, x, reg_tile)
-    edges_pad, regs_pad = h.shape[0], x.shape[0]
-    grid = (edges_pad // edge_block, regs_pad // reg_tile)
-    out = pl.pallas_call(
+    tab = edge_table((h, lo, thr), eb).T
+    _, x = pad_register_axis(None, x.astype(jnp.uint32), reg_tile)
+    edges_pad, regs_pad = tab.shape[0], x.shape[0]
+    out = pallas_call(
         partial(_fused_sample_kernel, predicate=predicate),
-        grid=grid,
+        grid=(edges_pad // eb, regs_pad // reg_tile),
         in_specs=[
-            pl.BlockSpec((edge_block,), lambda e, r: (e,)),
-            pl.BlockSpec((edge_block,), lambda e, r: (e,)),
-            pl.BlockSpec((edge_block,), lambda e, r: (e,)),
-            pl.BlockSpec((reg_tile,), lambda e, r: (r,)),
+            pl.BlockSpec((eb, 3), lambda e, r: (e, 0)),
+            pl.BlockSpec((1, reg_tile), lambda e, r: (0, r)),
         ],
-        out_specs=pl.BlockSpec((edge_block, reg_tile), lambda e, r: (e, r)),
+        out_specs=pl.BlockSpec((eb, reg_tile), lambda e, r: (e, r)),
         out_shape=jax.ShapeDtypeStruct((edges_pad, regs_pad), jnp.uint8),
-        interpret=interpret,
-    )(h, lo, thr, x)
-    if edges_pad != num_edges or regs_pad != num_regs:
-        out = out[:num_edges, :num_regs]
-    return out
+    )(tab, x[None, :])
+    return out[:num_edges, :num_regs]

@@ -8,22 +8,19 @@ sweep loop *inside* the launch: per register-lane tile, the current and
 accumulator panes stay in VMEM for all ``num_sweeps`` iterations and HBM
 sees the matrix exactly twice (load + final store).
 
-Schedule: grid = (J / lane_tile,), the edge operands broadcast whole to
-every grid instance (each tile loops all edges ``num_sweeps`` times — the
-fused trade: re-reading the small edge list buys register-pane residency).
+Schedule: grid = (J / lane_tile, num_sweeps, E / edge_block). The edge table
+streams through SMEM block by block, once per sweep (the fused trade:
+re-reading the small edge list buys register-pane residency).
 ``lane_tile`` is the model-aware FASST lane-fill knob surfaced by
 ``repro.tune`` as ``KernelConfig.lane_fill``: per-register-column
 independence of the Jacobi max-merge makes any tile width bit-identical, so
 density is purely a performance choice (``lt``'s remixed vertex hash
 changes which lanes are live per edge, shifting the optimum).
 
-VMEM working set per instance: two ``(n_pad, lane_tile)`` int8 panes (the
-ping-pong pair) plus the edge operands — at n_pad = 64Ki and lane_tile =
-128 that is 2 x 8 MiB panes, the same budget as the single-sweep kernel.
-
-The ping-pong pair is expressed as a second *output* pane rather than
-``scratch_shapes`` so the kernel also runs under old-jax interpret mode;
-the scratch pane is discarded by the wrapper.
+VMEM working set per lane tile: three single-buffered ``(n_pad, lane_tile)``
+int8 panes — the input, the accumulator (the output block) and the previous
+sweep's pane, a ``scratch_shapes`` buffer — which bounds the vertex count
+at 262,144 rows for lane tile 128 (``common.check_pane_rows``).
 
 Jacobi semantics: every sweep gathers from the previous sweep's pane only,
 so results are bit-identical to ``num_sweeps`` applications of
@@ -36,87 +33,72 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.sampling import edge_hash, fused_predicate
-from repro.kernels.common import REG_TILE, clamp_block
-from repro.kernels.sketch_propagate import (pad_edge_operands,
+from repro.kernels.common import (EDGE_BLOCK, REG_TILE, VISITED,
+                                  check_pane_rows, clamp_block,
+                                  compiler_params, edge_block_for, edge_mask,
+                                  edge_table, edge_table_spec, pad_rows,
+                                  pallas_call, pane_spec, read_row,
+                                  update_row)
+from repro.kernels.ref import edge_args
+from repro.kernels.sketch_propagate import (DST, HLT, SRC,
                                             pad_register_axis)
 
-VISITED = -1  # python literal: weak-typed inside kernels (no captured consts)
 
+def _fused_sweep_kernel(tab_ref, x_ref, m_ref, out_ref, prev_ref, *,
+                        edge_block: int, predicate):
+    sweep, eb = pl.program_id(1), pl.program_id(2)
 
-def _fused_sweep_kernel(src_ref, dst_ref, h_ref, lo_ref, thr_ref, x_ref,
-                        m_ref, out_ref, buf_ref, *, num_edges: int,
-                        num_sweeps: int, predicate):
-    src = src_ref[...]
-    dst = dst_ref[...]
-    h = h_ref[...].astype(jnp.uint32)
-    lo = lo_ref[...].astype(jnp.uint32)
-    thr = thr_ref[...].astype(jnp.uint32)
-    x = x_ref[...].astype(jnp.uint32)
+    @pl.when(jnp.logical_and(sweep == 0, eb == 0))
+    def _load():
+        out_ref[...] = m_ref[...]
 
-    buf_ref[...] = m_ref[...]          # "current" pane (previous sweep)
+    @pl.when(eb == 0)
+    def _next_sweep():
+        prev_ref[...] = out_ref[...]   # this sweep gathers from the last one
 
-    for _ in range(num_sweeps):        # static unroll: panes stay in VMEM
-        out_ref[...] = buf_ref[...]
+    x = x_ref[...]
 
-        def body(i, _):
-            u = src[i]
-            v = dst[i]
-            mask = predicate(h[i], lo[i], thr[i], x)  # fused sampling
-            pulled = pl.load(buf_ref, (v, slice(None)))  # Jacobi gather
-            contrib = jnp.where(mask, pulled, jnp.full_like(pulled, VISITED))
-            cur = pl.load(out_ref, (u, slice(None)))
-            # sticky visited: a VISITED register never resurrects
-            new = jnp.where(cur == VISITED, cur, jnp.maximum(cur, contrib))
-            pl.store(out_ref, (u, slice(None)), new)
-            return 0
+    def body(i, carry):
+        mask = edge_mask(tab_ref, i, HLT, x, predicate)  # fused sampling
+        pulled = read_row(prev_ref, tab_ref[DST, i])      # Jacobi gather
+        contrib = jnp.where(mask, pulled, VISITED)
+        # sticky visited: a VISITED register never resurrects
+        update_row(out_ref, tab_ref[SRC, i],
+                   lambda cur: jnp.where(cur == VISITED, cur,
+                                         jnp.maximum(cur, contrib)))
+        return carry
 
-        jax.lax.fori_loop(0, num_edges, body, 0)
-        buf_ref[...] = out_ref[...]    # ping-pong: next sweep reads this
+    jax.lax.fori_loop(0, edge_block, body, 0)
 
 
 @partial(jax.jit, static_argnames=("seed", "num_sweeps", "lane_tile",
-                                   "interpret", "predicate"))
+                                   "predicate"))
 def fused_sweep_pallas(m, src, dst, thr, x, h=None, lo=None, *, seed: int = 0,
                        num_sweeps: int = 1, lane_tile: int = REG_TILE,
-                       interpret: bool = True, predicate=None):
-    if h is None:
-        h = edge_hash(src, dst, seed=seed)
-    if lo is None:
-        lo = jnp.zeros(thr.shape, jnp.uint32)
-    if predicate is None:
-        predicate = fused_predicate
+                       predicate=None):
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
     if num_sweeps <= 0:
         return m
     n_pad, num_regs = m.shape
-    num_edges = int(src.shape[0])
     lane_tile = clamp_block(num_regs, lane_tile)
-    # edge padding keeps prime/odd edge counts legal on tiled backends
-    # (predicate-dead filler; see common.pad_amount) — the in-kernel loop
-    # still visits every padded slot, which is a no-op by construction
-    src, dst, h, lo, thr = pad_edge_operands(src, dst, h, lo, thr, 8)
-    e_pad = int(src.shape[0])
-    m_in, x = pad_register_axis(m, x, lane_tile)
+    rows = check_pane_rows("fused_sweep", n_pad, 3, lane_tile)
+    eb = edge_block_for(src.shape[0], EDGE_BLOCK)
+    tab = edge_table((src, dst, h, lo, thr), eb)
+    m_in, x = pad_register_axis(m, x.astype(jnp.uint32), lane_tile)
     regs_pad = x.shape[0]
-    grid = (regs_pad // lane_tile,)
-    out, _scratch = pl.pallas_call(
-        partial(_fused_sweep_kernel, num_edges=e_pad, num_sweeps=num_sweeps,
-                predicate=predicate),
-        grid=grid,
+    out = pallas_call(
+        partial(_fused_sweep_kernel, edge_block=eb, predicate=predicate),
+        grid=(regs_pad // lane_tile, num_sweeps, tab.shape[1] // eb),
         in_specs=[
-            pl.BlockSpec((e_pad,), lambda r: (0,)),
-            pl.BlockSpec((e_pad,), lambda r: (0,)),
-            pl.BlockSpec((e_pad,), lambda r: (0,)),
-            pl.BlockSpec((e_pad,), lambda r: (0,)),
-            pl.BlockSpec((e_pad,), lambda r: (0,)),
-            pl.BlockSpec((lane_tile,), lambda r: (r,)),
-            pl.BlockSpec((n_pad, lane_tile), lambda r: (0, r)),
+            edge_table_spec(5, eb, lambda r, s, e: (0, e)),
+            pl.BlockSpec((1, lane_tile), lambda r, s, e: (0, r)),
+            pane_spec(rows, lane_tile, lambda r, s, e: (0, r)),
         ],
-        out_specs=(pl.BlockSpec((n_pad, lane_tile), lambda r: (0, r)),
-                   pl.BlockSpec((n_pad, lane_tile), lambda r: (0, r))),
-        out_shape=(jax.ShapeDtypeStruct((n_pad, regs_pad), jnp.int8),
-                   jax.ShapeDtypeStruct((n_pad, regs_pad), jnp.int8)),
-        interpret=interpret,
-    )(src, dst, h, lo, thr, x, m_in)
-    return out[:, :num_regs] if regs_pad != num_regs else out
+        out_specs=pane_spec(rows, lane_tile, lambda r, s, e: (0, r)),
+        out_shape=jax.ShapeDtypeStruct((rows, regs_pad), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((rows, lane_tile), jnp.int8)],
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
+    )(tab, x[None, :], pad_rows(m_in, rows))
+    return out[:n_pad, :num_regs]

@@ -1,11 +1,12 @@
 """jit'd dispatch wrappers over the Pallas kernels and their jnp oracles.
 
 ``impl`` selects the backend:
-  * "ref"     — pure-jnp oracle (kernels/ref.py). Fast under XLA:CPU; the
-                default everywhere in this container.
-  * "pallas"  — Pallas body in interpret mode (CPU) — used by the kernel
-                equivalence tests; on a real TPU the same call sites flip
-                ``interpret=False``.
+  * "ref"     — pure-jnp oracle (kernels/ref.py); the default everywhere.
+  * "pallas"  — the Pallas kernel. It is Mosaic-compiled on a TPU and
+                interpreted on CPU — chosen per lowering platform by
+                ``kernels.common.pallas_call``, never by a switch here. A
+                shape a kernel cannot take on the chip raises with the
+                reason; nothing falls back to "ref" or the interpreter.
 
 All wrappers take the padded fixed-shape arrays produced by repro.graphs.
 
@@ -28,8 +29,6 @@ from repro.kernels.sketch_cardinality import cardinality_stats_pallas
 from repro.kernels.sketch_fill import sketch_fill_pallas
 from repro.kernels.sketch_propagate import propagate_sweep_pallas
 
-_INTERPRET = True  # flipped to False on real TPU deployments
-
 
 def fused_sample(src, dst, thr, x, *, seed: int = 0, impl: str = "ref",
                  h=None, lo=None, predicate=None,
@@ -38,7 +37,7 @@ def fused_sample(src, dst, thr, x, *, seed: int = 0, impl: str = "ref",
         return _ref.fused_sample_ref(src, dst, thr, x, h, lo, seed=seed,
                                      predicate=predicate)
     return fused_sample_pallas(src, dst, thr, x, h, lo, seed=seed,
-                               predicate=predicate, interpret=_INTERPRET,
+                               predicate=predicate,
                                edge_block=edge_block or EDGE_BLOCK,
                                reg_tile=reg_tile or REG_TILE)
 
@@ -46,7 +45,7 @@ def fused_sample(src, dst, thr, x, *, seed: int = 0, impl: str = "ref",
 def sketch_fill(m, *, reg_offset: int = 0, seed: int = 0, impl: str = "ref"):
     if impl == "ref":
         return _ref.sketch_fill_ref(m, reg_offset=reg_offset, seed=seed)
-    return sketch_fill_pallas(m, reg_offset=reg_offset, seed=seed, interpret=_INTERPRET)
+    return sketch_fill_pallas(m, reg_offset=reg_offset, seed=seed)
 
 
 def propagate_sweep(m, src, dst, thr, x, *, seed: int = 0, impl: str = "ref",
@@ -57,7 +56,7 @@ def propagate_sweep(m, src, dst, thr, x, *, seed: int = 0, impl: str = "ref",
             m, src, dst, thr, x, h, lo, seed=seed, predicate=predicate,
             edge_chunk=edge_chunk)
     return propagate_sweep_pallas(m, src, dst, thr, x, h, lo, seed=seed,
-                                  predicate=predicate, interpret=_INTERPRET,
+                                  predicate=predicate,
                                   edge_block=edge_block or EDGE_BLOCK,
                                   reg_tile=reg_tile or REG_TILE)
 
@@ -75,7 +74,6 @@ def fused_sweep(m, src, dst, thr, x, *, num_sweeps: int = 1, seed: int = 0,
             predicate=predicate, edge_chunk=edge_chunk, lane_fill=lane_fill)
     return fused_sweep_pallas(m, src, dst, thr, x, h, lo, seed=seed,
                               num_sweeps=num_sweeps, predicate=predicate,
-                              interpret=_INTERPRET,
                               lane_tile=lane_fill or reg_tile or REG_TILE)
 
 
@@ -87,7 +85,7 @@ def cascade_sweep(m, src, dst, thr, x, *, seed: int = 0, impl: str = "ref",
             m, src, dst, thr, x, h, lo, seed=seed, predicate=predicate,
             edge_chunk=edge_chunk)
     return cascade_sweep_pallas(m, src, dst, thr, x, h, lo, seed=seed,
-                                predicate=predicate, interpret=_INTERPRET,
+                                predicate=predicate,
                                 edge_block=edge_block or EDGE_BLOCK,
                                 reg_tile=reg_tile or REG_TILE)
 
@@ -96,5 +94,5 @@ def cardinality_stats(m, *, impl: str = "ref"):
     if impl == "ref":
         stat, count = _ref.cardinality_stats_ref(m)
     else:
-        stat, count = cardinality_stats_pallas(m, interpret=_INTERPRET)
+        stat, count = cardinality_stats_pallas(m)
     return jnp.stack([stat, count])

@@ -1,7 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
-These are the semantic ground truth: the Pallas bodies (interpret=True on
-CPU, TPU BlockSpecs for the target) must match them exactly — all kernels
+These are the semantic ground truth: the Pallas bodies (interpreted on
+CPU, Mosaic-compiled on a TPU) must match them exactly — all kernels
 here are integer/bit-exact except the cardinality statistics (float32,
 compared with allclose).
 
@@ -44,9 +44,9 @@ def _chunked(src, dst, h, lo, thr, edge_chunk: int):
     return tuple(a.reshape(n_chunks, edge_chunk) for a in ops), edge_chunk
 
 
-def _edge_args(src, dst, thr, h, lo, predicate, seed):
-    """Canonicalize the model hook: default hash/offset/predicate give the
-    legacy threshold compare."""
+def edge_args(src, dst, thr, h, lo, predicate, seed):
+    """Canonicalize the model hook (shared by the ref and Pallas sweeps):
+    default hash/offset/predicate give the legacy threshold compare."""
     if h is None:
         h = edge_hash(src, dst, seed=seed)
     if lo is None:
@@ -66,7 +66,7 @@ def fused_sample_ref(src: jnp.ndarray, dst: jnp.ndarray, thr: jnp.ndarray,
                      x: jnp.ndarray, h=None, lo=None, *, seed: int = 0,
                      predicate=None) -> jnp.ndarray:
     """(E,) edges × (R,) X -> (E, R) uint8 membership mask (paper eq. (2))."""
-    h, lo, predicate = _edge_args(src, dst, thr, h, lo, predicate, seed)
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
     return _edge_mask(h, lo, thr, x, predicate).astype(jnp.uint8)
 
 
@@ -95,7 +95,7 @@ def propagate_sweep_ref(m: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
     For every edge (u, v) live in sim j, M[u, j] <- max(M[u, j], M[v, j]).
     Visited registers are sticky. Jacobi: gathers read the input ``m``.
     """
-    h, lo, predicate = _edge_args(src, dst, thr, h, lo, predicate, seed)
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
     xs, _ = _chunked(src, dst, h, lo, thr, edge_chunk)
 
     def body(acc, chunk):
@@ -128,7 +128,7 @@ def fused_sweep_ref(m: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
     ``edge_chunk x num_regs`` to ``edge_chunk x lane_fill``, which is what
     keeps high-register-count sweeps cache-resident.
     """
-    h, lo, predicate = _edge_args(src, dst, thr, h, lo, predicate, seed)
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
     xs, _ = _chunked(src, dst, h, lo, thr, edge_chunk)
     num_regs = int(m.shape[1])
     fill = int(lane_fill) if 0 < int(lane_fill) < num_regs else num_regs
@@ -168,7 +168,7 @@ def cascade_sweep_ref(m: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
     For every edge (u, v) live in sim j with M[u, j] == VISITED, mark
     M[v, j] <- VISITED. Jacobi semantics as above.
     """
-    h, lo, predicate = _edge_args(src, dst, thr, h, lo, predicate, seed)
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
     xs, _ = _chunked(src, dst, h, lo, thr, edge_chunk)
     vis = m == VISITED
 

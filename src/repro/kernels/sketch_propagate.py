@@ -18,9 +18,14 @@ TPU adaptation of the CUDA kernel (see DESIGN.md §2):
 Grid = (J / REG_TILE, E / EDGE_BLOCK): the register tile is the outer
 (major) axis so the (n_pad x REG_TILE) register panes for input and
 accumulator stay VMEM-resident across all edge blocks (the classic
-reduction-innermost schedule). VMEM at (n_pad=64Ki, 128): two 8 MiB panes —
-the vertex dimension beyond that is tiled by the *distributed* vertex
-partition (core/distributed.py), not by this kernel.
+reduction-innermost schedule). Each pane is single-buffered; the two of
+them bound the vertex count (``common.check_pane_rows``: 393,216 rows at
+lane tile 128) — the vertex dimension beyond that is tiled by the
+*distributed* vertex partition (core/distributed.py), not by this kernel.
+
+The edge loop reads its per-edge scalars (src, dst, h, lo, thr) from one
+int32 table blocked into SMEM, and reads/writes int8 rows through their
+aligned (32, 128) tile (``common.read_row`` / ``update_row``).
 
 Jacobi semantics: gathers read the input pane, maxes accumulate into the
 output pane — bit-identical to kernels/ref.py for any edge order.
@@ -38,23 +43,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.sampling import edge_hash, fused_predicate
-from repro.kernels.common import (EDGE_BLOCK, REG_TILE, clamp_block,
-                                 pad_amount)
+from repro.kernels.common import (EDGE_BLOCK, REG_TILE, VISITED,
+                                  check_pane_rows, clamp_block,
+                                  compiler_params, edge_block_for, edge_mask,
+                                  edge_table, edge_table_spec, pad_amount,
+                                  pad_rows, pallas_call, pane_spec, read_row,
+                                  update_row)
+from repro.kernels.ref import edge_args
 
-VISITED = -1  # python literal: weak-typed inside kernels (no captured consts)
-
-
-def pad_edge_operands(src, dst, h, lo, thr, edge_block: int):
-    """Round the edge axis up to a multiple of ``edge_block`` with
-    predicate-dead filler (thr=0 never fires, so padded edges contribute the
-    max-merge identity) — any block size is legal, including on prime edge
-    counts where the old largest-divisor search degraded to block=1."""
-    pad = pad_amount(src.shape[0], edge_block)
-    if pad:
-        src, dst, h, lo, thr = (jnp.pad(a, (0, pad))
-                                for a in (src, dst, h, lo, thr))
-    return src, dst, h, lo, thr
+#: rows of the SMEM edge table the sweep kernels read: src, dst, h, lo, thr
+SRC, DST, HLT = 0, 1, (2, 3, 4)
 
 
 def pad_register_axis(m, x, reg_tile: int):
@@ -69,69 +67,59 @@ def pad_register_axis(m, x, reg_tile: int):
     return m, x
 
 
-def _propagate_kernel(src_ref, dst_ref, h_ref, lo_ref, thr_ref, x_ref, m_ref,
-                      out_ref, *, edge_block: int, predicate):
-    eb = pl.program_id(1)
-
-    @pl.when(eb == 0)
+def _propagate_kernel(tab_ref, x_ref, m_ref, out_ref, *, edge_block: int,
+                      predicate):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = m_ref[...]
 
-    src = src_ref[...]
-    dst = dst_ref[...]
-    h = h_ref[...].astype(jnp.uint32)
-    lo = lo_ref[...].astype(jnp.uint32)
-    thr = thr_ref[...].astype(jnp.uint32)
-    x = x_ref[...].astype(jnp.uint32)
+    x = x_ref[...]
 
-    def body(i, _):
-        u = src[i]
-        v = dst[i]
-        mask = predicate(h[i], lo[i], thr[i], x)  # (R_TILE,) fused sampling
-        pulled = pl.load(m_ref, (v, slice(None)))  # Jacobi gather of v's tile
-        contrib = jnp.where(mask, pulled, jnp.full_like(pulled, VISITED))
-        cur = pl.load(out_ref, (u, slice(None)))
+    def body(i, carry):
+        mask = edge_mask(tab_ref, i, HLT, x, predicate)  # fused sampling
+        pulled = read_row(m_ref, tab_ref[DST, i])  # Jacobi gather of v's tile
+        contrib = jnp.where(mask, pulled, VISITED)
         # sticky visited: a VISITED register never resurrects
-        new = jnp.where(cur == VISITED, cur, jnp.maximum(cur, contrib))
-        pl.store(out_ref, (u, slice(None)), new)
-        return 0
+        update_row(out_ref, tab_ref[SRC, i],
+                   lambda cur: jnp.where(cur == VISITED, cur,
+                                         jnp.maximum(cur, contrib)))
+        return carry
 
     jax.lax.fori_loop(0, edge_block, body, 0)
 
 
-@partial(jax.jit, static_argnames=("seed", "edge_block", "reg_tile", "interpret",
+def edge_sweep_call(kernel, name: str, m, src, dst, thr, x, h, lo, *,
+                    edge_block: int, reg_tile: int, predicate):
+    """Launch one (register tile x edge block) sweep kernel over the packed
+    edge table; shared by the SIMULATE and CASCADE sweeps."""
+    n_pad, num_regs = m.shape
+    reg_tile = clamp_block(num_regs, reg_tile)
+    rows = check_pane_rows(name, n_pad, 2, reg_tile)
+    eb = edge_block_for(src.shape[0], edge_block)
+    tab = edge_table((src, dst, h, lo, thr), eb)
+    m_in, x = pad_register_axis(m, x.astype(jnp.uint32), reg_tile)
+    regs_pad = x.shape[0]
+    out = pallas_call(
+        partial(kernel, edge_block=eb, predicate=predicate),
+        grid=(regs_pad // reg_tile, tab.shape[1] // eb),
+        in_specs=[
+            edge_table_spec(5, eb, lambda r, e: (0, e)),
+            pl.BlockSpec((1, reg_tile), lambda r, e: (0, r)),
+            pane_spec(rows, reg_tile, lambda r, e: (0, r)),
+        ],
+        out_specs=pane_spec(rows, reg_tile, lambda r, e: (0, r)),
+        out_shape=jax.ShapeDtypeStruct((rows, regs_pad), jnp.int8),
+        compiler_params=compiler_params("parallel", "arbitrary"),
+    )(tab, x[None, :], pad_rows(m_in, rows))
+    return out[:n_pad, :num_regs]
+
+
+@partial(jax.jit, static_argnames=("seed", "edge_block", "reg_tile",
                                    "predicate"))
 def propagate_sweep_pallas(m, src, dst, thr, x, h=None, lo=None, *, seed: int = 0,
                            edge_block: int = EDGE_BLOCK, reg_tile: int = REG_TILE,
-                           interpret: bool = True, predicate=None):
-    if h is None:
-        h = edge_hash(src, dst, seed=seed)
-    if lo is None:
-        lo = jnp.zeros(thr.shape, jnp.uint32)
-    if predicate is None:
-        predicate = fused_predicate
-    n_pad, num_regs = m.shape
-    num_edges = src.shape[0]
-    reg_tile = clamp_block(num_regs, reg_tile)
-    edge_block = clamp_block(num_edges, edge_block)
-    src, dst, h, lo, thr = pad_edge_operands(src, dst, h, lo, thr, edge_block)
-    m_in, x = pad_register_axis(m, x, reg_tile)
-    regs_pad = x.shape[0]
-    grid = (regs_pad // reg_tile, src.shape[0] // edge_block)
-    out = pl.pallas_call(
-        partial(_propagate_kernel, edge_block=edge_block, predicate=predicate),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((edge_block,), lambda r, e: (e,)),
-            pl.BlockSpec((reg_tile,), lambda r, e: (r,)),
-            pl.BlockSpec((n_pad, reg_tile), lambda r, e: (0, r)),
-        ],
-        out_specs=pl.BlockSpec((n_pad, reg_tile), lambda r, e: (0, r)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, regs_pad), jnp.int8),
-        interpret=interpret,
-    )(src, dst, h, lo, thr, x, m_in)
-    return out[:, :num_regs] if regs_pad != num_regs else out
+                           predicate=None):
+    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
+    return edge_sweep_call(_propagate_kernel, "propagate_sweep", m, src, dst,
+                           thr, x, h, lo, edge_block=edge_block,
+                           reg_tile=reg_tile, predicate=predicate)

@@ -4,11 +4,11 @@
         --k 50 --registers 1024 --devices 8 --validate
 
 Execution is selected by ``--backend`` (repro.runtime registry):
-``auto`` resolves to the jitted single-device driver for an unsharded run,
-to the ``shard_map`` mesh runtime when ``--devices > 1`` and jax supports
-it (export XLA_FLAGS=--xla_force_host_platform_device_count=N for a host
-mesh), and to the serial-ring executor otherwise — all three return
-bit-identical seed sets.
+``auto`` resolves to the jitted single-device driver for an unsharded run
+and to the ``shard_map`` mesh runtime when ``--devices > 1`` (export
+XLA_FLAGS=--xla_force_host_platform_device_count=N for a host mesh). With
+too few devices it runs the serial-ring executor on CPU and raises on an
+accelerator — all three return bit-identical seed sets.
 """
 from __future__ import annotations
 
@@ -111,4 +111,7 @@ def _run(args) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.common import enable_compile_cache
+
+    enable_compile_cache()
     run()

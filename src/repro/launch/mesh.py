@@ -10,18 +10,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import Mesh
-
-from repro.utils.jax_compat import JAX_HAS_AXIS_TYPE
-
-# jax API drift: AxisType landed after the 0.4.x line (single source of
-# truth for the guard: utils/jax_compat.py)
-if JAX_HAS_AXIS_TYPE:
-    from jax.sharding import AxisType
-
-    _MESH_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-else:  # pragma: no cover - exercised only on old jax
-    _MESH_KW = lambda n: {}  # noqa: E731
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -36,14 +25,14 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax (see launch/dryrun.py)")
     return jax.make_mesh(shape, axes, devices=devices[:ndev],
-                         **_MESH_KW(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple, axes: tuple) -> Mesh:
     """Arbitrary mesh for tests/benchmarks (uses the first prod(shape) devices)."""
     ndev = math.prod(shape)
     return jax.make_mesh(shape, axes, devices=jax.devices()[:ndev],
-                         **_MESH_KW(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serving_mesh(mu_v: int, *, vertex_axis: str = "data",

@@ -195,4 +195,7 @@ def _run(args) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.common import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -491,7 +491,7 @@ def _section_tuning(tuning) -> str:
                 f"<td>{float(m.get('tuned_us', 0)):,.0f} µs</td>"
                 f"<td>{_status(speedup >= 0.999, f'{speedup:.2f}x')}</td>"
                 f"<td>{float(m.get('tuned_gbps', 0)):.2f}</td>"
-                f"<td>{float(m.get('frac_of_roof', 0)) * 100:.1f}%</td>"
+                f"<td>{float(m.get('frac_of_roof') or 0) * 100:.1f}%</td>"
                 "</tr>")
         else:
             trs.append(

@@ -111,7 +111,7 @@ class MeasuredProfile:
 
     def achieved_gbps(self) -> float:
         """Aggregate bucket bytes / wall — the bandwidth this build actually
-        sustained (compare against ``utils.roofline.HBM_BW``)."""
+        sustained (compare against the chip's ``utils.roofline.PEAKS`` entry)."""
         total = float(self.step_bytes.sum())
         return total / self.wall_s / 1e9 if self.wall_s > 0 else 0.0
 

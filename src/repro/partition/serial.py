@@ -8,8 +8,7 @@ Three jobs:
   * **planner invariance tests** — seed sets and spread estimates must be
     identical across every :mod:`repro.partition.plan` strategy (and any
     random relabeling), and they must match the single-device ``find_seeds``
-    path; this executor makes that testable without a multi-device mesh
-    (old-jax containers skip the ``shard_map`` suite entirely);
+    path; this executor makes that testable without a multi-device mesh;
   * **benchmarks** — ``benchmarks/partition_balance.py`` times real bucket
     sweeps per planner without device multiplexing noise;
   * **reference** — a readable spelling of the ring schedule (the

@@ -6,7 +6,7 @@ One :class:`Backend` protocol, three registered implementations —
     numerics, always available);
   * ``serial`` — the serial-ring executor (the 2-D ring schedule on one
     host; always available; the only backend with per-shard repair);
-  * ``mesh``   — the shard_map 2-D runtime (needs new-enough jax + devices)
+  * ``mesh``   — the shard_map 2-D runtime (needs mu_v * mu_s devices)
 
 — selected by :class:`RunSpec` (``backend="auto"`` picks the best available
 strategy for the requested shard grid), behind one facade object,

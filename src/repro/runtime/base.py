@@ -22,9 +22,11 @@ supports it. ``resolve_backend`` implements ``backend="auto"``:
   1. an explicit name is honored (and raises with the reason when that
      backend cannot run here);
   2. ``spec.num_shards <= 1`` and no mesh given -> ``single``;
-  3. otherwise ``mesh`` if the jax version + device count allow it,
-     else ``serial`` (the always-available fallback — the exact ring
-     schedule, one host).
+  3. otherwise ``mesh`` if the device count allows it. Only on CPU does
+     a grid too large for the devices run on ``serial`` instead (the exact
+     ring schedule, one host — the test stand-in for a mesh); on an
+     accelerator it raises :class:`BackendUnavailable` with the mesh's
+     reason, so a sharded request never silently runs another path.
 """
 from __future__ import annotations
 
@@ -201,9 +203,15 @@ def resolve_backend(spec: RunSpec, g: Optional[Graph] = None, *,
     if mesh is None and spec.num_shards <= 1:
         return get_backend("single")
     b = get_backend("mesh")
-    ok, _ = b.supports(g, spec)
+    ok, why_mesh = b.supports(g, spec)
     if ok:
         return b
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise BackendUnavailable(
+            f"the spec asks for a {spec.mu_v} x {spec.mu_s} shard grid and "
+            f"the mesh backend cannot run it here: {why_mesh}")
     serial = get_backend("serial")
     ok, why = serial.supports(g, spec)
     if not ok:

@@ -1,10 +1,9 @@
 """``mesh`` backend — the shard_map 2-D distributed runtime.
 
-Wraps ``core/distributed.py``. Needs a jax new enough to ship
-``jax.sharding.AxisType`` (the ``JAX_HAS_AXIS_TYPE`` guard) and at least
-``mu_v * mu_s`` devices; otherwise ``supports`` says no and ``auto``
-resolution falls back to the ``serial`` backend, which executes the exact
-same ring schedule (results are bit-identical by contract).
+Wraps ``core/distributed.py``. Needs at least ``mu_v * mu_s`` devices;
+otherwise ``supports`` says no, and ``auto`` resolution raises on an
+accelerator (on CPU it runs the ``serial`` backend, which executes the exact
+same ring schedule — results are bit-identical by contract).
 """
 from __future__ import annotations
 
@@ -18,7 +17,6 @@ from repro.graphs.structs import Graph
 from repro.runtime.base import (Backend, BackendCapabilities, RunReport,
                                 apply_tuning, register_backend)
 from repro.runtime.spec import RunSpec
-from repro.utils.jax_compat import JAX_HAS_AXIS_TYPE
 
 
 class MeshBackend(Backend):
@@ -31,17 +29,7 @@ class MeshBackend(Backend):
             description="shard_map 2-D runtime (ring/allgather schedules; "
                         "shard-restricted repair of device-resident banks)")
 
-    def available(self):
-        if not JAX_HAS_AXIS_TYPE:
-            return False, ("jax.sharding.AxisType missing (old jax) — the "
-                           "shard_map runtime needs a newer jax; the 'serial' "
-                           "backend runs the same schedule meanwhile")
-        return True, ""
-
     def supports(self, g, spec: RunSpec):
-        ok, why = self.available()
-        if not ok:
-            return ok, why
         import jax
 
         ndev = len(jax.devices())
@@ -123,11 +111,6 @@ class MeshBackend(Backend):
         should be the placement mesh of the matrix (a device-resident
         entry's); without one, a row-only serving mesh of ``plan.mu_v``
         devices is constructed."""
-        ok, why = self.available()
-        if not ok:
-            from repro.runtime.base import BackendUnavailable
-
-            raise BackendUnavailable(f"mesh backend: {why}")
         from repro.core import distributed as _dist
 
         if mesh is None:

@@ -113,12 +113,6 @@ class InfluenceSession:
             return
         if e.residency == "device":
             return
-        from repro.utils.jax_compat import JAX_HAS_AXIS_TYPE
-
-        if not JAX_HAS_AXIS_TYPE:
-            raise BackendUnavailable(
-                "device residency needs jax.sharding.AxisType (newer jax); "
-                "residency='host' serves the same answers host-order")
         shards = (e.plan.mu_v if e.plan is not None
                   else max(self.spec.mu_v if self.spec.mu_v > 1
                            else self.spec.num_shards, 1))

@@ -91,13 +91,16 @@ def _measurement_record(family: str, backend: str, results) -> dict:
     ``(config, label, seconds, gbps)`` with the *first* entry the default."""
     default_s = results[0][2]
     best = min(results, key=lambda r: r[2])
+    peaks = roofline.device_peaks()
     return {
         "family": family, "backend": backend,
         "default_us": round(default_s * 1e6, 3),
         "tuned_us": round(best[2] * 1e6, 3),
         "tuned_gbps": round(best[3], 3),
-        "frac_of_roof": round(best[3] * 1e9 / roofline.HBM_BW, 6),
         "speedup": round(default_s / best[2], 4) if best[2] > 0 else 1.0,
+        # fraction of the HBM roof, only for a chip in roofline.PEAKS
+        "frac_of_roof": (round(best[3] * 1e9 / peaks.hbm_bw, 6)
+                         if peaks is not None else None),
         "candidates": [
             {"label": lab, "config": cfg.to_dict(),
              "us": round(s * 1e6, 3), "gbps": round(g, 3)}

@@ -1,13 +1,38 @@
-"""Three-term roofline model (TPU v5e-like constants fixed by the
-assignment): compute / memory / collective times from the compiled dry-run
-artifact, the dominant bottleneck, and the useful-FLOPs ratio."""
+"""Published chip peaks, and the three-term roofline model of the dry-run:
+compute / memory / collective times from the compiled artifact, the
+dominant bottleneck, and the useful-FLOPs ratio."""
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
-PEAK_FLOPS = 197e12       # bf16 FLOP/s per chip
-HBM_BW = 819e9            # B/s per chip
-ICI_BW = 50e9             # B/s per link (1 link assumed per transfer — conservative)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float          # bf16 FLOP/s per chip
+    hbm_bw: float         # HBM B/s per chip
+    ici_link_bw: float    # chip-to-chip B/s per link
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+#: 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_link_bw=50e9),
+}
+
+#: the chip the dry-run roofline is drawn for
+V5E = PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device_kind: Optional[str] = None) -> Optional[ChipPeaks]:
+    """Peaks of ``device_kind`` (default: the first visible device's), or
+    None for a device the table does not list — never a default."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    return PEAKS.get(device_kind)
 
 
 @dataclasses.dataclass
@@ -23,15 +48,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / V5E.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / V5E.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.wire_bytes_per_device / ICI_BW
+        return self.wire_bytes_per_device / V5E.ici_link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -54,7 +79,7 @@ class Roofline:
         only useful (model) FLOPs: (model_flops/chips / t_bound) / peak."""
         if self.t_bound == 0:
             return 0.0
-        return (self.model_flops_total / self.chips / self.t_bound) / PEAK_FLOPS
+        return (self.model_flops_total / self.chips / self.t_bound) / V5E.flops
 
     def to_dict(self) -> dict:
         return {
@@ -72,29 +97,19 @@ class Roofline:
         }
 
 
-def annotate_bandwidth(sp, nbytes: int, seconds: float) -> float:
-    """Attach achieved GB/s and fraction-of-roof (vs :data:`HBM_BW`) to a
-    trace span, so Perfetto lanes carry bandwidth attribution next to the
-    wall time. ``sp`` may be a null span (tracing disabled) — ``annotate``
-    is then a no-op and only the return value (GB/s) is meaningful. Returns
-    0.0 for degenerate timings instead of raising."""
+def annotate_bandwidth(sp, nbytes: int, seconds: float,
+                       device_kind: Optional[str] = None) -> float:
+    """Attach achieved GB/s to a trace span, plus its fraction of the HBM
+    peak when the device is in :data:`PEAKS` (no fraction for any other
+    device: a CPU run has no roof here). ``sp`` may be a null span (tracing
+    disabled) — ``annotate`` is then a no-op and only the return value
+    (GB/s) is meaningful. Returns 0.0 for degenerate timings instead of
+    raising."""
     if seconds <= 0 or nbytes <= 0:
         return 0.0
     gbps = nbytes / seconds / 1e9
-    sp.annotate(achieved_gbps=round(gbps, 3),
-                frac_of_roof=round(gbps * 1e9 / HBM_BW, 6))
+    sp.annotate(achieved_gbps=round(gbps, 3))
+    peaks = device_peaks(device_kind)
+    if peaks is not None:
+        sp.annotate(frac_of_roof=round(gbps * 1e9 / peaks.hbm_bw, 6))
     return gbps
-
-
-def model_flops(cfg, shape) -> float:
-    """MODEL_FLOPS for the cell: 6·N_active·D for training, 2·N_active·D for
-    inference (D = tokens processed in the lowered step)."""
-    n_active = cfg.active_param_count()
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n_active * tokens
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n_active * tokens
-    # decode: one token per sequence
-    return 2.0 * n_active * shape.global_batch

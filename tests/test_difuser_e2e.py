@@ -80,7 +80,8 @@ def test_more_registers_better_estimates():
 
 
 def test_pallas_impl_end_to_end():
-    """The full driver also runs with the Pallas-interpret kernels."""
+    """The full driver also runs with the Pallas kernels (interpreted on
+    CPU)."""
     g = rmat_graph(7, edge_factor=6, seed=17, setting="w1")
     ref = find_seeds(g, 3, DiFuserConfig(num_registers=128, seed=6, impl="ref"))
     pal = find_seeds(g, 3, DiFuserConfig(num_registers=128, seed=6, impl="pallas"))

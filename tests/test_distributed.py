@@ -10,12 +10,6 @@ import sys
 
 import pytest
 
-from repro.core.distributed import JAX_HAS_AXIS_TYPE
-
-pytestmark = pytest.mark.skipif(
-    not JAX_HAS_AXIS_TYPE,
-    reason="jax.sharding.AxisType missing (old jax) — mesh/shard_map API drift")
-
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs the jnp
+"""Per-kernel shape/dtype sweeps: Pallas (interpreted on CPU) vs the jnp
 oracles. Integer kernels must match bit-exactly; float statistics allclose.
 """
 import jax.numpy as jnp

@@ -13,6 +13,29 @@ def test_im_cli_end_to_end(capsys):
     assert rel < 0.25
 
 
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The launchers' cache: JAX's own directory when
+    JAX_COMPILATION_CACHE_DIR is set, else the checkout's fixed one."""
+    import jax
+
+    from repro.launch.common import COMPILE_CACHE_DIR, enable_compile_cache
+
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert enable_compile_cache() == str(COMPILE_CACHE_DIR)
+        assert COMPILE_CACHE_DIR.parent.joinpath("chip_smoke.py").exists()
+        own = str(tmp_path / "cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", own)
+        jax.config.update("jax_compilation_cache_dir", own)  # as JAX reads it
+        assert enable_compile_cache() == own
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          saved[1])
+
+
 def test_ft_supervisor_restarts_until_success(tmp_path):
     """A command that fails twice then succeeds is relaunched transparently."""
     from repro.launch.ft import supervise
@@ -41,7 +64,7 @@ def test_elastic_snapshot_roundtrip(tmp_path):
     """The FT story's index half: a server relaunch restores the persisted
     SketchStore snapshot (plan included) instead of re-running the cold
     fixpoint, on any topology (host restore here; mesh restore is the
-    AxisType-guarded half in test_sharded_serving.py)."""
+    mesh half of test_sharded_serving.py)."""
     import numpy as np
 
     from repro.core.difuser import DiFuserConfig

@@ -24,7 +24,6 @@ from repro.runtime import (BackendUnavailable, InfluenceSession, RunSpec,
                            available_backends, get_backend, resolve_backend,
                            run)
 from repro.service import SketchStore, apply_delta
-from repro.utils.jax_compat import JAX_HAS_AXIS_TYPE
 
 MODELS = ["wc", "ic:0.2", "lt", "dic:0.5"]
 STRATEGIES = ["block", "degree", "edge", "random"]
@@ -69,8 +68,6 @@ def test_serial_backend_invariance(model, strategy):
 @pytest.mark.parametrize("model", MODELS)
 @pytest.mark.parametrize("strategy", STRATEGIES)
 def test_mesh_backend_invariance(model, strategy):
-    if not JAX_HAS_AXIS_TYPE:
-        pytest.skip("jax.sharding.AxisType missing (old jax) — API drift")
     import jax
 
     if len(jax.devices()) < 4:
@@ -93,18 +90,29 @@ def test_auto_resolution_rules():
     g = _graph()
     assert resolve_backend(_spec(), g).name == "single"
     sharded = resolve_backend(_spec(mu_v=2, mu_s=2), g)
-    if JAX_HAS_AXIS_TYPE:
-        import jax
+    import jax
 
-        expect = "mesh" if len(jax.devices()) >= 4 else "serial"
-    else:
-        expect = "serial"
+    # too few devices for the mesh: the serial ring stands in on CPU only
+    expect = "mesh" if len(jax.devices()) >= 4 else "serial"
     assert sharded.name == expect
     with pytest.raises(KeyError):
         get_backend("warp-drive")
-    if not JAX_HAS_AXIS_TYPE:
+    if len(jax.devices()) < 4:
         with pytest.raises(BackendUnavailable):
             resolve_backend(_spec(backend="mesh", mu_v=2, mu_s=2), g)
+
+
+def test_auto_never_substitutes_serial_on_an_accelerator(monkeypatch):
+    """Off CPU, a shard grid the devices cannot hold raises with the mesh's
+    reason instead of quietly running the serial ring."""
+    import jax
+
+    g = _graph()
+    too_many = len(jax.devices()) + 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(BackendUnavailable, match="device"):
+        resolve_backend(_spec(mu_v=too_many, mu_s=1), g)
+    assert resolve_backend(_spec(), g).name == "single"
 
 
 def test_registry_reports_capabilities():
@@ -157,8 +165,6 @@ def test_shim_find_seeds_ring_serial_byte_identical():
 
 
 def test_shim_find_seeds_distributed_byte_identical():
-    if not JAX_HAS_AXIS_TYPE:
-        pytest.skip("jax.sharding.AxisType missing (old jax) — API drift")
     import jax
 
     if len(jax.devices()) < 4:
@@ -191,14 +197,13 @@ def test_store_banks_build_through_any_backend():
         st = SketchStore(num_banks=2, backend="serial", spec=spec)
         m = np.asarray(st.get_or_build(g, cfg).matrix)
         np.testing.assert_array_equal(m, ref)
-    if JAX_HAS_AXIS_TYPE:
-        import jax
+    import jax
 
-        if len(jax.devices()) >= 2:
-            st = SketchStore(num_banks=2, backend="mesh",
-                             spec=RunSpec(mu_v=2, mu_s=1))
-            m = np.asarray(st.get_or_build(g, cfg).matrix)
-            np.testing.assert_array_equal(m, ref)
+    if len(jax.devices()) >= 2:
+        st = SketchStore(num_banks=2, backend="mesh",
+                         spec=RunSpec(mu_v=2, mu_s=1))
+        m = np.asarray(st.get_or_build(g, cfg).matrix)
+        np.testing.assert_array_equal(m, ref)
 
 
 def test_session_warm_matches_cold_across_backends():

@@ -9,11 +9,11 @@ Two tiers:
   units: ``QueryResult.backend`` accounting, ``apply_delta(backend="auto")``
   on host entries, placement preconditions, npz residency field.
 
-* **AxisType-guarded** — real ``NamedSharding`` placement on a host-device
-  mesh: all four query classes bit-identical device vs host, mesh
+* **mesh** — real ``NamedSharding`` placement on a host-device mesh: all
+  four query classes bit-identical device vs host, mesh
   ``repair_plan_shards`` == serial repair == full rebuild, session
-  residency routing, snapshot round-trip onto a mesh. These run in the
-  ``test-jax-latest`` CI job (8 fake devices).
+  residency routing, snapshot round-trip onto a mesh. These need
+  ``MU_V`` devices and run in CI's 8-fake-device pass.
 """
 import numpy as np
 import pytest
@@ -27,14 +27,11 @@ from repro.service import (CoverageProbe, InfluenceEngine, MarginalGain,
                            SketchStore, SpreadEstimate, TopKSeeds, apply_delta,
                            summarize_latencies)
 from repro.service import queries as Q
-from repro.utils.jax_compat import JAX_HAS_AXIS_TYPE
 
 MU_V = 4
 
 
 def _mesh_ready(mu_v=MU_V):
-    if not JAX_HAS_AXIS_TYPE:
-        return False, "jax.sharding.AxisType missing (old jax) — API drift"
     import jax
 
     if len(jax.devices()) < mu_v:
@@ -234,7 +231,7 @@ def test_runspec_residency_resolution():
 
 
 # ---------------------------------------------------------------------------
-# AxisType-guarded: real placement on a host-device mesh
+# mesh: real placement on a host-device mesh
 # ---------------------------------------------------------------------------
 
 

@@ -320,16 +320,15 @@ def test_serial_local_sweeps_result_invariant(small_graph):
 
 
 # ---------------------------------------------------------------------------
-# prime-edge-count regression (kernels/common.py pad+mask instead of the
-# largest-divisor pick_block, whose worst case was block=1 on prime axes)
+# prime-edge-count regression (kernels/common.py pad+mask instead of a
+# largest-divisor block search, whose worst case was block=1 on prime axes)
 # ---------------------------------------------------------------------------
 
 
 def test_clamp_and_pad_prime_axis():
-    from repro.kernels.common import clamp_block, pad_amount, pick_block
+    from repro.kernels.common import clamp_block, pad_amount
 
-    assert pick_block(997, 512) == 1            # the footgun, documented
-    assert clamp_block(997, 512) == 512         # the fix: clamp ...
+    assert clamp_block(997, 512) == 512         # clamp ...
     assert pad_amount(997, 512) == 27           # ... and pad to 1024
     assert clamp_block(251, 512) == 251         # block never exceeds the axis
     assert pad_amount(251, 251) == 0
