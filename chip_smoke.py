@@ -41,8 +41,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 #: the full-size job (step 1 of the bring-up): graph spec, registers, seeds
 GRAPH, REGISTERS, K = "rmat:20", 1024, 50
 #: seeds the smoke actually asks for: at K = 50 the cold job and its warm
-#: twin (each ~6 min of dense cascade sweeps on one v5e) would take the
-#: smoke past its 20-minute limit; K is cut, never the scale or J
+#: twin (each ~6-7 min of dense cascade sweeps on one v5e) would leave the
+#: smoke little margin under its 20-minute limit; K is cut, never the
+#: scale or J
 SMOKE_K = 25
 SETTING, MODEL = "0.1", "wc"
 #: phase (b): small enough to build on the host CPU in seconds
