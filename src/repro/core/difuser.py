@@ -33,8 +33,7 @@ from repro.core.sketch import VISITED, count_visited
 from repro.diffusion.constants import DEFAULT_MODEL
 from repro.graphs.structs import Graph
 from repro.kernels import ops
-from repro.obs import trace
-from repro.utils import roofline
+from repro.obs import metrics, trace
 
 
 def resolve_model(spec: str):
@@ -74,6 +73,21 @@ class InfluenceResult:
     rebuilds: np.ndarray       # bool[K]   whether round i rebuilt sketches
     propagate_iters: int       # initial build fixpoint sweeps
     x: np.ndarray              # the random vector actually used (uint32[J])
+    cascade_iters: np.ndarray  # int32[K]  cascade sweeps of round i
+    rebuild_iters: np.ndarray  # int32[K]  rebuild fixpoint sweeps (0: kept)
+
+    def sweep_counts(self) -> dict:
+        """The job span's sweep counters: first fixpoint, cascades, rebuilds."""
+        return {"build_sweeps": int(self.propagate_iters),
+                "cascade_sweeps": int(np.sum(self.cascade_iters)),
+                "rebuild_sweeps": int(np.sum(self.rebuild_iters))}
+
+
+def annotate_job(sp, res: InfluenceResult, compiles0: int) -> None:
+    """Put ``res``'s sweep counters and the backend compiles since
+    ``compiles0`` (``jit.compiles``) on the job span ``sp``."""
+    sp.annotate(**res.sweep_counts(),
+                compiles=metrics.counter("jit.compiles").value - compiles0)
 
 
 def edge_operands(g: Graph, cfg: DiFuserConfig):
@@ -84,6 +98,19 @@ def edge_operands(g: Graph, cfg: DiFuserConfig):
     ep = resolve_model(cfg.model).edge_params(g, seed=cfg.seed)
     return (jnp.asarray(g.src), jnp.asarray(g.dst), jnp.asarray(ep.h),
             jnp.asarray(ep.lo), jnp.asarray(ep.thr))
+
+
+def _normalized(g: Graph, cfg: DiFuserConfig, x):
+    """``normalize_inputs`` under the ``entry.normalize_inputs`` span."""
+    with trace.span("entry.normalize_inputs", phase="plan", m=g.m):
+        return normalize_inputs(g, cfg, x)
+
+
+def _uploaded(g: Graph, cfg: DiFuserConfig):
+    """``edge_operands`` under the ``entry.edge_operands`` span, which syncs
+    the uploaded operands."""
+    with trace.span("entry.edge_operands", phase="plan", m=g.m) as sp:
+        return sp.sync(edge_operands(g, cfg))
 
 
 def _init_registers(n_pad: int, n_real: int, num_regs: int) -> jnp.ndarray:
@@ -102,51 +129,63 @@ def _seed_rounds(m, src, dst, h, lo, thr, x, *, k, n_real, num_regs, seed,
     Shared by the cold path (``find_seeds``) and the warm-start path
     (``find_seeds_warm`` / service.SketchStore) so both trace the identical
     round program — warm seeds are byte-identical to cold seeds.
+
+    The round's phases sit under the named scopes ``difuser.select``,
+    ``difuser.cascade`` and ``difuser.rebuild`` (op metadata only), and each
+    round also returns its cascade and rebuild sweep counts.
     """
 
     def round_fn(carry, _):
         m, score, oldscore = carry
-        sums = _select.local_sums(m, impl=impl)
-        s, gain = _select.finish_select(sums, num_regs, n_real, estimator=estimator)
-        m, _ = cascade_from_seed(m, s, src, dst, thr, x, h, lo, seed=seed,
-                                 impl=impl,
-                                 edge_chunk=cascade_chunk or edge_chunk,
-                                 max_iters=max_casc, predicate=predicate,
-                                 edge_block=edge_block, reg_tile=reg_tile)
-        visited = count_visited(m, n_real).astype(jnp.float32)
+        with jax.named_scope("difuser.select"):
+            sums = _select.local_sums(m, impl=impl)
+            s, gain = _select.finish_select(sums, num_regs, n_real,
+                                            estimator=estimator)
+        with jax.named_scope("difuser.cascade"):
+            m, casc_iters = cascade_from_seed(
+                m, s, src, dst, thr, x, h, lo, seed=seed, impl=impl,
+                edge_chunk=cascade_chunk or edge_chunk, max_iters=max_casc,
+                predicate=predicate, edge_block=edge_block, reg_tile=reg_tile)
+            visited = count_visited(m, n_real).astype(jnp.float32)
         new_score = visited / jnp.float32(num_regs)
         rel = (new_score - oldscore) / jnp.maximum(new_score, 1e-9)
         do_rebuild = rel > rebuild_threshold
 
         def rebuild(m):
-            m2 = ops.sketch_fill(m, reg_offset=0, seed=seed, impl=impl)
-            m2, _ = propagate_to_fixpoint(m2, src, dst, thr, x, h, lo, seed=seed,
-                                          impl=impl, edge_chunk=edge_chunk,
-                                          max_iters=max_prop, predicate=predicate,
-                                          edge_block=edge_block, reg_tile=reg_tile)
-            return m2, new_score
+            with jax.named_scope("difuser.rebuild"):
+                m2 = ops.sketch_fill(m, reg_offset=0, seed=seed, impl=impl)
+                m2, iters = propagate_to_fixpoint(
+                    m2, src, dst, thr, x, h, lo, seed=seed, impl=impl,
+                    edge_chunk=edge_chunk, max_iters=max_prop,
+                    predicate=predicate, edge_block=edge_block,
+                    reg_tile=reg_tile)
+            return m2, new_score, iters
 
         def keep(m):
-            return m, oldscore
+            return m, oldscore, jnp.int32(0)
 
-        m, oldscore = jax.lax.cond(do_rebuild, rebuild, keep, m)
-        return (m, new_score, oldscore), (s, gain, new_score, do_rebuild)
+        m, oldscore, rebuild_iters = jax.lax.cond(do_rebuild, rebuild, keep, m)
+        return (m, new_score, oldscore), (s, gain, new_score, do_rebuild,
+                                          casc_iters, rebuild_iters)
 
     (_, _, _), outs = jax.lax.scan(round_fn, (m, jnp.float32(0.0), jnp.float32(0.0)),
                                    None, length=k)
-    return outs  # (seeds, gains, scores, rebuilds)
+    # (seeds, gains, scores, rebuilds, cascade_iters, rebuild_iters)
+    return outs
 
 
 def _build_matrix(src, dst, h, lo, thr, x, n_pad, *, n_real, num_regs, seed, impl,
                   edge_chunk, max_prop, reg_offset=0, predicate=None,
                   edge_block=0, reg_tile=0):
-    """Alg. 4 lines 3-6: init + fill + propagate-to-fixpoint. Returns (m, iters)."""
-    m = _init_registers(n_pad, n_real, num_regs)
-    m = ops.sketch_fill(m, reg_offset=reg_offset, seed=seed, impl=impl)
-    return propagate_to_fixpoint(
-        m, src, dst, thr, x, h, lo, seed=seed, impl=impl, edge_chunk=edge_chunk,
-        max_iters=max_prop, predicate=predicate, edge_block=edge_block,
-        reg_tile=reg_tile)
+    """Alg. 4 lines 3-6: init + fill + propagate-to-fixpoint, under the
+    named scope ``difuser.fixpoint``. Returns (m, iters)."""
+    with jax.named_scope("difuser.fixpoint"):
+        m = _init_registers(n_pad, n_real, num_regs)
+        m = ops.sketch_fill(m, reg_offset=reg_offset, seed=seed, impl=impl)
+        return propagate_to_fixpoint(
+            m, src, dst, thr, x, h, lo, seed=seed, impl=impl,
+            edge_chunk=edge_chunk, max_iters=max_prop, predicate=predicate,
+            edge_block=edge_block, reg_tile=reg_tile)
 
 
 def _find_seeds(src, dst, h, lo, thr, x, n_pad, *, k, n_real, num_regs, seed,
@@ -157,13 +196,14 @@ def _find_seeds(src, dst, h, lo, thr, x, n_pad, *, k, n_real, num_regs, seed,
         src, dst, h, lo, thr, x, n_pad, n_real=n_real, num_regs=num_regs,
         seed=seed, impl=impl, edge_chunk=edge_chunk, max_prop=max_prop,
         predicate=predicate, edge_block=edge_block, reg_tile=reg_tile)
-    seeds, gains, scores, rebuilds = _seed_rounds(
+    seeds, gains, scores, rebuilds, casc_iters, rebuild_iters = _seed_rounds(
         m, src, dst, h, lo, thr, x, k=k, n_real=n_real, num_regs=num_regs,
         seed=seed, estimator=estimator, impl=impl, edge_chunk=edge_chunk,
         max_prop=max_prop, max_casc=max_casc,
         rebuild_threshold=rebuild_threshold, predicate=predicate,
         cascade_chunk=cascade_chunk, edge_block=edge_block, reg_tile=reg_tile)
-    return seeds, gains, scores, rebuilds, build_iters
+    return (seeds, gains, scores, rebuilds, build_iters, casc_iters,
+            rebuild_iters)
 
 
 #: the performance-only tile statics shared by the jitted drivers
@@ -191,13 +231,16 @@ def _find_seeds_single(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
                        x: Optional[np.ndarray] = None) -> InfluenceResult:
     """Single-device Alg. 4 driver (the ``single`` runtime backend's body).
     ``x`` overrides the random vector (the distributed tests use this to pin
-    identical sample spaces)."""
+    identical sample spaces). The ``single.find_seeds`` span covers the
+    whole call, host preparation included, and carries the job's sweep
+    counters and compiles."""
     cfg = config or DiFuserConfig()
-    g, x = normalize_inputs(g, cfg, x)
-    src, dst, h, lo, thr = edge_operands(g, cfg)
+    compiles0 = metrics.counter("jit.compiles").value
     with trace.span("single.find_seeds", phase="select", k=k, n=g.n,
                     registers=cfg.num_registers, model=cfg.model) as sp:
-        seeds, gains, scores, rebuilds, build_iters = sp.sync(_find_seeds_jit(
+        g, x = _normalized(g, cfg, x)
+        src, dst, h, lo, thr = _uploaded(g, cfg)
+        out = jax.device_get(_find_seeds_jit(
             src, dst, h, lo, thr, jnp.asarray(x),
             n_pad=g.n_pad, k=k, n_real=g.n, num_regs=cfg.num_registers,
             seed=cfg.seed, estimator=cfg.estimator, impl=cfg.impl,
@@ -207,10 +250,13 @@ def _find_seeds_single(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
             predicate=resolve_model(cfg.model).predicate,
             cascade_chunk=cfg.cascade_chunk, edge_block=cfg.edge_block,
             reg_tile=cfg.reg_tile))
-    return InfluenceResult(
-        seeds=np.asarray(seeds), est_gains=np.asarray(gains),
-        scores=np.asarray(scores), rebuilds=np.asarray(rebuilds),
-        propagate_iters=int(build_iters), x=np.asarray(x))
+        seeds, gains, scores, rebuilds, build_iters, casc, reb = out
+        res = InfluenceResult(
+            seeds=seeds, est_gains=gains, scores=scores, rebuilds=rebuilds,
+            propagate_iters=int(build_iters), x=np.asarray(x),
+            cascade_iters=casc, rebuild_iters=reb)
+        annotate_job(sp, res, compiles0)
+    return res
 
 
 def find_seeds(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
@@ -272,8 +318,8 @@ def build_sketch_matrix(g: Graph, config: Optional[DiFuserConfig] = None,
     """
     cfg = config or DiFuserConfig()
     if not normalized:
-        g, x = normalize_inputs(g, cfg, x)
-    src, dst, h, lo, thr = edges if edges is not None else edge_operands(g, cfg)
+        g, x = _normalized(g, cfg, x)
+    src, dst, h, lo, thr = edges if edges is not None else _uploaded(g, cfg)
     predicate = resolve_model(cfg.model).predicate
     with trace.span("single.build_matrix", phase="build", n=g.n,
                     registers=int(x.shape[0]), reg_offset=reg_offset,
@@ -293,10 +339,6 @@ def build_sketch_matrix(g: Graph, config: Optional[DiFuserConfig] = None,
                 edge_block=cfg.edge_block, reg_tile=cfg.reg_tile)
         sp.sync(m)
         sp.annotate(iters=int(iters))
-    # bandwidth attribution: per sweep each real edge reads its ~20 B of
-    # operands (src/dst/h/lo/thr) plus one int8 read + write per register
-    nbytes = int(iters) * int(g.m_real) * (20 + 2 * int(x.shape[0]))
-    roofline.annotate_bandwidth(sp, nbytes, sp.duration_s)
     return m, int(iters), x
 
 
@@ -313,12 +355,12 @@ def find_seeds_warm(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
     path, skipping the per-query O(m log m) host sort and re-upload."""
     cfg = config or DiFuserConfig()
     if edges is None:
-        g, x = normalize_inputs(g, cfg, x)
-        edges = edge_operands(g, cfg)
+        g, x = _normalized(g, cfg, x)
+        edges = _uploaded(g, cfg)
     src, dst, h, lo, thr = edges
     with trace.span("single.warm_rounds", phase="select", k=k, n=g.n,
                     registers=int(x.shape[0])) as sp:
-        seeds, gains, scores, rebuilds = sp.sync(_seed_rounds_jit(
+        seeds, gains, scores, rebuilds, casc, reb = sp.sync(_seed_rounds_jit(
             matrix, src, dst, h, lo, thr,
             jnp.asarray(x), k=k, n_real=g.n, num_regs=x.shape[0], seed=cfg.seed,
             estimator=cfg.estimator, impl=cfg.impl, edge_chunk=cfg.edge_chunk,
@@ -330,4 +372,5 @@ def find_seeds_warm(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
     return InfluenceResult(
         seeds=np.asarray(seeds), est_gains=np.asarray(gains),
         scores=np.asarray(scores), rebuilds=np.asarray(rebuilds),
-        propagate_iters=0, x=np.asarray(x))
+        propagate_iters=0, x=np.asarray(x), cascade_iters=np.asarray(casc),
+        rebuild_iters=np.asarray(reb))

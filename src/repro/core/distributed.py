@@ -50,12 +50,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import sketch
-from repro.core.difuser import DiFuserConfig, InfluenceResult, resolve_model
+from repro.core.difuser import (DiFuserConfig, InfluenceResult, annotate_job,
+                                resolve_model)
 from repro.core.sampling import fused_predicate
 from repro.core.sampling import make_x_vector
 from repro.core.sketch import VISITED
 from repro.graphs.structs import Graph
-from repro.obs import shardprof, trace
+from repro.obs import metrics, shardprof, trace
 # host-side partition build moved to repro.partition; re-exported here for
 # backward compatibility (tests and dryrun historically imported from core)
 from repro.partition import (Partition2D, build_partition_2d,  # noqa: F401
@@ -108,6 +109,11 @@ def _make_distributed_fn(part: Partition2D, *, k: int, vertex_axis: str,
     (the K seed rounds are the identical program either way, so warm mesh
     seeds are bit-identical to cold mesh seeds, which are bit-identical to
     the single-device reference).
+
+    The phases carry the single-device driver's named scopes
+    (``difuser.fixpoint``/``select``/``cascade``/``rebuild``), and the body
+    returns per-round cascade and rebuild sweep counts after the seeds,
+    gains, scores, rebuild flags and first-fixpoint sweeps.
     """
     mu_v, mu_s = part.mu_v, part.mu_s
     n_loc, j_loc, n_real = part.n_loc, part.j_loc, part.n
@@ -221,58 +227,66 @@ def _make_distributed_fn(part: Partition2D, *, k: int, vertex_axis: str,
             # (fresh is still needed above for the lazy-rebuild refill)
             m_loc, build_iters = m_in, jnp.int32(0)
         else:
-            m_loc = jnp.where(valid_row[:, None], fresh.astype(jnp.int8),
-                              jnp.int8(VISITED))
-            m_loc, build_iters = fixpoint(m_loc, ph, pw, pr, pt, pl, x_loc,
-                                          _bucket_sweep_propagate, max_prop)
+            with jax.named_scope("difuser.fixpoint"):
+                m_loc = jnp.where(valid_row[:, None], fresh.astype(jnp.int8),
+                                  jnp.int8(VISITED))
+                m_loc, build_iters = fixpoint(m_loc, ph, pw, pr, pt, pl,
+                                              x_loc, _bucket_sweep_propagate,
+                                              max_prop)
 
         # ---- K seed rounds ----
         def round_fn(carry, _):
             m_cur, score, oldscore = carry
-            # selection: psum stats over sim axes -> exact for owned rows
-            stats = jnp.stack([
-                jnp.sum(jnp.where(m_cur != VISITED, jnp.exp2(-m_cur.astype(jnp.float32)), 0.0), axis=-1),
-                jnp.sum(m_cur != VISITED, axis=-1).astype(jnp.float32)])
-            stats = jax.lax.psum(stats, tuple(sim_axes)) if sim_axes else stats
-            est = sketch.estimate_from_sums(stats, total_regs, estimator=estimator)
-            est = jnp.where(valid_row, est, -1.0)
-            # min-original-id tie-break: under a relabeling plan, ids are
-            # scattered across shards, so plain argmax (lowest local row)
-            # would break bit-identity between planners on est ties
-            loc_best = jnp.max(est)
-            loc_seed = jnp.min(jnp.where(est == loc_best, owned,
-                                         jnp.int32(part.n_pad)))
-            # cross-shard argmax: P scalars instead of the paper's O(n) vector
-            bests = jax.lax.all_gather(loc_best, vertex_axis)        # (mu_v,)
-            seeds_g = jax.lax.all_gather(loc_seed, vertex_axis)      # (mu_v,)
-            gain = jnp.max(bests)
-            s_global = jnp.min(jnp.where(bests == gain, seeds_g,
-                                         jnp.int32(part.n_pad)))
-            # commit + cascade
-            m_cur = jnp.where((owned == s_global)[:, None], jnp.int8(VISITED), m_cur)
-            m_cur, _ = fixpoint(m_cur, ch, cw, cr, ct, cl, x_loc,
-                                _bucket_sweep_cascade, max_casc)
-            visited = jnp.sum(jnp.logical_and(m_cur == VISITED, valid_row[:, None]).astype(jnp.int32))
-            visited = jax.lax.psum(visited, all_axes).astype(jnp.float32)
+            with jax.named_scope("difuser.select"):
+                # selection: psum stats over sim axes -> exact for owned rows
+                stats = jnp.stack([
+                    jnp.sum(jnp.where(m_cur != VISITED, jnp.exp2(-m_cur.astype(jnp.float32)), 0.0), axis=-1),
+                    jnp.sum(m_cur != VISITED, axis=-1).astype(jnp.float32)])
+                stats = jax.lax.psum(stats, tuple(sim_axes)) if sim_axes else stats
+                est = sketch.estimate_from_sums(stats, total_regs, estimator=estimator)
+                est = jnp.where(valid_row, est, -1.0)
+                # min-original-id tie-break: under a relabeling plan, ids are
+                # scattered across shards, so plain argmax (lowest local row)
+                # would break bit-identity between planners on est ties
+                loc_best = jnp.max(est)
+                loc_seed = jnp.min(jnp.where(est == loc_best, owned,
+                                             jnp.int32(part.n_pad)))
+                # cross-shard argmax: P scalars instead of the paper's O(n)
+                bests = jax.lax.all_gather(loc_best, vertex_axis)    # (mu_v,)
+                seeds_g = jax.lax.all_gather(loc_seed, vertex_axis)  # (mu_v,)
+                gain = jnp.max(bests)
+                s_global = jnp.min(jnp.where(bests == gain, seeds_g,
+                                             jnp.int32(part.n_pad)))
+            with jax.named_scope("difuser.cascade"):
+                # commit + cascade
+                m_cur = jnp.where((owned == s_global)[:, None], jnp.int8(VISITED), m_cur)
+                m_cur, casc_iters = fixpoint(m_cur, ch, cw, cr, ct, cl, x_loc,
+                                             _bucket_sweep_cascade, max_casc)
+                visited = jnp.sum(jnp.logical_and(m_cur == VISITED, valid_row[:, None]).astype(jnp.int32))
+                visited = jax.lax.psum(visited, all_axes).astype(jnp.float32)
             new_score = visited / jnp.float32(total_regs)
             rel = (new_score - oldscore) / jnp.maximum(new_score, 1e-9)
 
             def rebuild(mm):
-                mm = refill(mm)
-                mm, _ = fixpoint(mm, ph, pw, pr, pt, pl, x_loc,
-                                 _bucket_sweep_propagate, max_prop)
-                return mm, new_score
+                with jax.named_scope("difuser.rebuild"):
+                    mm = refill(mm)
+                    mm, iters = fixpoint(mm, ph, pw, pr, pt, pl, x_loc,
+                                         _bucket_sweep_propagate, max_prop)
+                return mm, new_score, iters
 
             def keep(mm):
-                return mm, oldscore
+                return mm, oldscore, jnp.int32(0)
 
-            m_cur, oldscore = jax.lax.cond(rel > rebuild_threshold, rebuild, keep, m_cur)
-            return (m_cur, new_score, oldscore), (s_global, gain, new_score, rel > rebuild_threshold)
+            m_cur, oldscore, rebuild_iters = jax.lax.cond(
+                rel > rebuild_threshold, rebuild, keep, m_cur)
+            return (m_cur, new_score, oldscore), (
+                s_global, gain, new_score, rel > rebuild_threshold,
+                casc_iters, rebuild_iters)
 
         (_, _, _), outs = jax.lax.scan(round_fn, (m_loc, jnp.float32(0.0), jnp.float32(0.0)),
                                        None, length=k)
-        seeds_out, gains, scores, rebuilds = outs
-        return seeds_out, gains, scores, rebuilds, build_iters
+        seeds_out, gains, scores, rebuilds, casc, reb = outs
+        return seeds_out, gains, scores, rebuilds, build_iters, casc, reb
 
     # helper resolved at trace time inside shard_map
     _axis_sizes: dict[str, int] = {}
@@ -303,8 +317,8 @@ class DistributedConfig(DiFuserConfig):
     pad_mode: str = "step"          # "step" | "global" bucket padding
 
 
-def _publish_mesh_profile(part, *, phase: str, sweeps: int, wall_s: float,
-                          span) -> None:
+def _publish_mesh_profile(part, *, phase: str, sweeps: int,
+                          wall_s: float) -> None:
     """Measured-profile publication for the SPMD paths. Mesh shards execute
     in lockstep inside one XLA program, so per-shard wall time is not
     separable host-side — the profile carries exact per-(shard, ring step)
@@ -313,13 +327,21 @@ def _publish_mesh_profile(part, *, phase: str, sweeps: int, wall_s: float,
     (``per_step_timed=False``; the serial twin supplies measured times)."""
     if not shardprof.enabled():
         return
-    from repro.utils import roofline
-
     prof = shardprof.profile_for_partition(part, backend="mesh", phase=phase)
     prof.add_partition_bytes(np.asarray(part.p_counts), part.j_loc, sweeps)
     predicted = part.plan.predicted if part.plan is not None else None
-    mp = shardprof.publish(prof.finish(wall_s), predicted=predicted)
-    roofline.annotate_bandwidth(span, int(mp.step_bytes.sum()), wall_s)
+    shardprof.publish(prof.finish(wall_s), predicted=predicted)
+
+
+def _sample_edge_sets(g: Graph, x: np.ndarray, mu_s: int,
+                      cfg: DistributedConfig):
+    """``sample_edge_sets`` under the ``partition.sample_edge_sets`` span.
+    Returns (sampled, method)."""
+    method = "fasst" if cfg.fasst else "naive"
+    with trace.span("partition.sample_edge_sets", phase="plan", mu_s=mu_s,
+                    method=method):
+        return sample_edge_sets(g, x, mu_s, seed=cfg.seed, model=cfg.model,
+                                method=method), method
 
 
 def _find_seeds_distributed(g: Graph, k: int, mesh,
@@ -331,21 +353,32 @@ def _find_seeds_distributed(g: Graph, k: int, mesh,
     Seeds/estimates come back in original vertex ids for every
     ``cfg.partition`` strategy (the relabeling is un-permuted on device via
     ``owned_ids``). ``plan`` overrides the ``cfg.partition``-derived
-    :class:`PartitionPlan` (results are plan-invariant either way).
+    :class:`PartitionPlan` (results are plan-invariant either way). The
+    ``mesh.find_seeds`` span covers the whole call, the per-call partition
+    included, and carries the job's sweep counters and compiles.
     """
-    from jax.sharding import PartitionSpec as P
-
     cfg = config or DistributedConfig()
     mu_v = mesh.shape[cfg.vertex_axis]
     mu_s = math.prod(mesh.shape[ax] for ax in cfg.sim_axes)
+    compiles0 = metrics.counter("jit.compiles").value
+    with trace.span("mesh.find_seeds", phase="select", k=k, mu_v=mu_v,
+                    mu_s=mu_s, schedule=cfg.schedule) as sp:
+        res, part = _run_distributed(g, k, mesh, cfg, x, plan, mu_v, mu_s)
+        annotate_job(sp, res, compiles0)
+    return res, part
+
+
+def _run_distributed(g: Graph, k: int, mesh, cfg: DistributedConfig, x,
+                     plan, mu_v: int, mu_s: int):
+    """The body of :func:`_find_seeds_distributed`, inside its job span."""
+    from jax.sharding import PartitionSpec as P
+
     if x is None:
         x = make_x_vector(cfg.num_registers, seed=cfg.seed)
     g = g.sorted_by_dst()
-    method = "fasst" if cfg.fasst else "naive"
     # the O(m * mu_s) sampled-edge preprocessing feeds both the planner and
     # the bucket build — run it once
-    sampled = sample_edge_sets(g, x, mu_s, seed=cfg.seed, model=cfg.model,
-                               method=method)
+    sampled, method = _sample_edge_sets(g, x, mu_s, cfg)
     if plan is None:
         plan = plan_partition(g, mu_v, mu_s=mu_s, strategy=cfg.partition,
                               seed=cfg.seed, model=cfg.model, method=method,
@@ -367,7 +400,7 @@ def _find_seeds_distributed(g: Graph, k: int, mesh,
     bucket_spec = P(cfg.vertex_axis, sim_spec, None)
     n_buckets = 10 * part.mu_v
     in_specs = (P(sim_spec, None), P(cfg.vertex_axis, None)) + (bucket_spec,) * n_buckets
-    out_specs = (P(), P(), P(), P(), P())
+    out_specs = (P(),) * 7
 
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
@@ -378,15 +411,14 @@ def _find_seeds_distributed(g: Graph, k: int, mesh,
         for step in field:
             args.append(jnp.asarray(step))
     t0 = perf_counter()
-    with trace.span("mesh.find_seeds", phase="select", k=k, mu_v=mu_v,
-                    mu_s=mu_s, schedule=cfg.schedule) as sp:
-        seeds, gains, scores, rebuilds, build_iters = sp.sync(fn(*args))
+    seeds, gains, scores, rebuilds, build_iters, casc, reb = jax.device_get(
+        fn(*args))
     _publish_mesh_profile(part, phase="select", sweeps=int(build_iters),
-                          wall_s=perf_counter() - t0, span=sp)
+                          wall_s=perf_counter() - t0)
     res = InfluenceResult(
-        seeds=np.asarray(seeds), est_gains=np.asarray(gains), scores=np.asarray(scores),
-        rebuilds=np.asarray(rebuilds), propagate_iters=int(build_iters),
-        x=np.sort(x) if cfg.fasst else x)
+        seeds=seeds, est_gains=gains, scores=scores, rebuilds=rebuilds,
+        propagate_iters=int(build_iters), x=np.sort(x) if cfg.fasst else x,
+        cascade_iters=casc, rebuild_iters=reb)
     return res, part
 
 
@@ -541,9 +573,7 @@ def build_matrix_distributed(g: Graph, mesh,
         if cfg.fasst:
             x = np.sort(x)
     x = np.asarray(x, dtype=np.uint32)
-    method = "fasst" if cfg.fasst else "naive"
-    sampled = sample_edge_sets(g, x, mu_s, seed=cfg.seed, model=cfg.model,
-                               method=method)
+    sampled, method = _sample_edge_sets(g, x, mu_s, cfg)
     if plan is None:
         plan = plan_partition(g, mu_v, mu_s=mu_s, strategy=cfg.partition,
                               seed=cfg.seed, model=cfg.model, method=method,
@@ -577,7 +607,7 @@ def build_matrix_distributed(g: Graph, mesh,
         # un-permute planned rows back to original-id (canonical) order
         m_canon = sp.sync(m_planned[jnp.asarray(part.plan.perm[: g.n_pad])])
     _publish_mesh_profile(part, phase="build", sweeps=int(iters),
-                          wall_s=perf_counter() - t0, span=sp)
+                          wall_s=perf_counter() - t0)
     return m_canon, int(iters), part
 
 
@@ -607,9 +637,7 @@ def _partition_for_plan(g: Graph, mesh, cfg: DistributedConfig,
         raise ValueError(f"plan has mu_v={plan.mu_v} but the mesh's "
                          f"{cfg.vertex_axis!r} axis is {mu_v}-way")
     x = np.asarray(x, dtype=np.uint32)
-    method = "fasst" if cfg.fasst else "naive"
-    sampled = sample_edge_sets(g, x, mu_s, seed=cfg.seed, model=cfg.model,
-                               method=method)
+    sampled, method = _sample_edge_sets(g, x, mu_s, cfg)
     return build_partition_2d(g, x, mu_v, mu_s, seed=cfg.seed, method=method,
                               model=cfg.model, plan=plan,
                               pad_mode=cfg.pad_mode, sampled=sampled)
@@ -651,7 +679,7 @@ def find_seeds_warm_distributed(g: Graph, k: int, mesh,
     bucket_spec = P(cfg.vertex_axis, sim_spec, None)
     in_specs = ((P(cfg.vertex_axis, sim_spec), P(sim_spec, None),
                  P(cfg.vertex_axis, None)) + (bucket_spec,) * (10 * part.mu_v))
-    out_specs = (P(), P(), P(), P(), P())
+    out_specs = (P(),) * 7
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False))
@@ -663,11 +691,12 @@ def find_seeds_warm_distributed(g: Graph, k: int, mesh,
             args.append(jnp.asarray(step))
     with trace.span("mesh.warm_rounds", phase="select", k=k,
                     mu_v=part.mu_v, mu_s=part.mu_s) as sp:
-        seeds, gains, scores, rebuilds, _ = sp.sync(fn(*args))
+        seeds, gains, scores, rebuilds, _, casc, reb = sp.sync(fn(*args))
     return InfluenceResult(
         seeds=np.asarray(seeds), est_gains=np.asarray(gains),
         scores=np.asarray(scores), rebuilds=np.asarray(rebuilds),
-        propagate_iters=0, x=np.sort(x) if cfg.fasst else x)
+        propagate_iters=0, x=np.sort(x) if cfg.fasst else x,
+        cascade_iters=np.asarray(casc), rebuild_iters=np.asarray(reb))
 
 
 def _make_repair_fn(part: Partition2D, *, vertex_axis: str,

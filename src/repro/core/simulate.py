@@ -22,8 +22,13 @@ import jax.numpy as jnp
 from repro.kernels import ops
 
 
+# inline: a program that runs the fixpoint at two sites (the first build and
+# the lazy rebuild) gets its own copy at each, so each copy's device ops keep
+# the named scope of their site; a shared callee carried both scopes on the
+# TPU compiler's op metadata. The compiled code is the same either way.
 @partial(jax.jit, static_argnames=("seed", "impl", "edge_chunk", "max_iters",
-                                   "predicate", "edge_block", "reg_tile"))
+                                   "predicate", "edge_block", "reg_tile"),
+         inline=True)
 def propagate_to_fixpoint(m, src, dst, thr, x, h=None, lo=None, *, seed: int = 0,
                           impl: str = "ref", edge_chunk: int = 2048,
                           max_iters: int = 64, predicate=None,

@@ -118,7 +118,7 @@ def lower_im_cell(name: str, mesh, *, k: int = 4, schedule: str = "ring",
     in_specs = ((P(sim_spec, None), P(vertex_axis, None))
                 + (bucket_spec,) * (10 * mu_v))
     fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=(P(), P(), P(), P(), P()), check_vma=False))
+                               out_specs=(P(),) * 7, check_vma=False))
     bshape = (mu_v, mu_s, bucket)
     args = [_sds((mu_s, j_loc), jnp.uint32), _sds((mu_v, n_loc), jnp.int32)]
     for dt in (jnp.uint32, jnp.int32, jnp.int32, jnp.uint32, jnp.uint32) * 2:

@@ -33,7 +33,6 @@ from repro.obs import shardprof, trace
 from repro.partition.builder import Partition2D, build_partition_2d
 from repro.partition.plan import (PartitionPlan, plan_partition,
                                   sample_edge_sets)
-from repro.utils import roofline
 
 
 def _est_from_sums_np(stat, cnt, total_regs: int, estimator: str):
@@ -356,23 +355,24 @@ def _find_seeds_ring_serial(g: Graph, k: int,
     if st.profiler is not None:
         # null spans report duration 0.0 (tracing off) -> let the profiler
         # fall back to its own wall clock
-        prof = shardprof.publish(st.profiler.finish(sp.duration_s or None),
-                                 predicted=plan.predicted)
-        roofline.annotate_bandwidth(sp, int(prof.step_bytes.sum()),
-                                    prof.wall_s)
+        shardprof.publish(st.profiler.finish(sp.duration_s or None),
+                          predicted=plan.predicted)
         st.profiler = None   # rounds reuse the state; profile is the build's
 
     seeds = np.zeros(k, dtype=np.int32)
     gains = np.zeros(k, dtype=np.float32)
     scores = np.zeros(k, dtype=np.float32)
     rebuilds = np.zeros(k, dtype=bool)
+    casc_iters = np.zeros(k, dtype=np.int32)
+    rebuild_iters = np.zeros(k, dtype=np.int32)
     oldscore = np.float32(0.0)
     for i in range(k):
         with trace.span("serial.round", phase="select", round=i) as rsp:
             s_v, gain = st.select(total_regs, part.n_pad)
             st.commit(s_v)
             with trace.span("serial.cascade_fixpoint", phase="ring", round=i):
-                st.fixpoint(st.sweep_cascade, cfg.max_cascade_iters)
+                casc_iters[i] = st.fixpoint(st.sweep_cascade,
+                                            cfg.max_cascade_iters)
             new_score = np.float32(st.visited_count()) / np.float32(total_regs)
             rel = (new_score - oldscore) / np.maximum(new_score,
                                                       np.float32(1e-9))
@@ -380,13 +380,15 @@ def _find_seeds_ring_serial(g: Graph, k: int,
             if do_rebuild:
                 with trace.span("serial.rebuild", phase="build", round=i):
                     st.refill()
-                    st.fixpoint(st.sweep_propagate, cfg.max_propagate_iters)
+                    rebuild_iters[i] = st.fixpoint(st.sweep_propagate,
+                                                   cfg.max_propagate_iters)
                 oldscore = new_score
             rsp.annotate(seed=s_v, rebuild=do_rebuild)
         seeds[i], gains[i], scores[i], rebuilds[i] = s_v, gain, new_score, do_rebuild
     res = InfluenceResult(seeds=seeds, est_gains=gains, scores=scores,
                           rebuilds=rebuilds, propagate_iters=build_iters,
-                          x=np.sort(x))
+                          x=np.sort(x), cascade_iters=casc_iters,
+                          rebuild_iters=rebuild_iters)
     return res, part
 
 
@@ -457,10 +459,8 @@ def build_matrix_ring_serial(g: Graph, config: Optional[DiFuserConfig] = None,
         iters = st.fixpoint(st.sweep_propagate, cfg.max_propagate_iters)
         sp.annotate(iters=iters)
     if st.profiler is not None:
-        prof = shardprof.publish(st.profiler.finish(sp.duration_s or None),
-                                 predicted=plan.predicted)
-        roofline.annotate_bandwidth(sp, int(prof.step_bytes.sum()),
-                                    prof.wall_s)
+        shardprof.publish(st.profiler.finish(sp.duration_s or None),
+                          predicted=plan.predicted)
     return st.canonical_matrix(g.n_pad), iters, part
 
 

@@ -32,6 +32,9 @@ from __future__ import annotations
 import warnings
 from typing import Optional
 
+import jax
+
+from repro.obs import metrics
 from repro.runtime.base import (Backend, BackendCapabilities,
                                 BackendUnavailable, RunReport,
                                 available_backends, get_backend,
@@ -45,6 +48,20 @@ from repro.runtime import serial as _serial   # noqa: F401,E402
 from repro.runtime import mesh as _mesh       # noqa: F401,E402
 
 from repro.runtime.session import InfluenceSession  # noqa: E402
+
+#: the event JAX records around every backend compile of a program not in
+#: its in-memory cache (a persistent-cache load counts too)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _count_compile(event: str, duration: float, **_) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        metrics.counter("jit.compiles").inc()
+
+
+# one listener per process: the job spans read ``jit.compiles`` around
+# each call (their ``compiles`` attribute)
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 def run(g, k: int, spec: Optional[RunSpec] = None, *, x=None, mesh=None,

@@ -3,7 +3,7 @@
 ``repro.tune`` turns the repo's hard-coded tile shapes and sweep schedules
 into measured decisions: a per-kernel-family :class:`KernelConfig` search
 space, an autotuner that times candidates through the ``obs.trace`` timed
-spans (device-synced, roofline-annotated), and a persistent
+spans (device-synced), and a persistent
 :class:`TuningCache` keyed by kernel × backend × diffusion model ×
 size-bucket. The runtime backends consult :func:`resolve_spec` behind the
 ``RunSpec.tuning`` knob ("off" | "cached" | "auto"); tuning is

@@ -2,10 +2,9 @@
 
 Measurement goes through the same instruments the rest of the repo trusts:
 each candidate runs inside a ``trace.span(..., timed=True)`` (device output
-synced *inside* the span, so queueing is not mistaken for execution) and is
-annotated with achieved GB/s and fraction-of-roof via
-``utils.roofline.annotate_bandwidth``. Winners are the candidate with the
-best min-of-N wall time; every trial also lands in the ``tune.*`` metric
+synced *inside* the span, so queueing is not mistaken for execution), and
+its achieved GB/s is computed from its best time. Winners are the candidate
+with the best min-of-N wall time; every trial also lands in the ``tune.*`` metric
 namespace so the perf report can show what the tuner saw.
 
 ``resolve_spec`` is the one hook the runtime backends call: with
@@ -52,9 +51,8 @@ def _time_grid(fns, labels, *, family: str, nbytes: int,
 
     Every trial runs inside a ``trace.span(..., timed=True)`` with the
     candidate's output declared via ``sp.sync`` — device work lands inside
-    the measurement — and is roofline-annotated with achieved GB/s, so
-    tuning trials show up as their own Perfetto lanes next to the workload
-    they tuned. Interleaving matters: warm-up drift (allocator, caches,
+    the measurement — so tuning trials show up as their own Perfetto lanes
+    next to the workload they tuned. Interleaving matters: warm-up drift (allocator, caches,
     CPU frequency) is monotone within a process, so timing candidates
     back-to-back in blocks would systematically favor whichever ran last.
     Round-robin rounds spread the drift evenly; min-per-candidate then
@@ -70,7 +68,6 @@ def _time_grid(fns, labels, *, family: str, nbytes: int,
                             family=family, candidate=labels[i]) as sp:
                 sp.sync(fn())
             best[i] = min(best[i], sp.duration_s)
-            roofline.annotate_bandwidth(sp, nbytes, sp.duration_s)
     return [(s, (nbytes / s / 1e9) if s > 0 and nbytes > 0 else 0.0)
             for s in best]
 

@@ -96,20 +96,3 @@ class Roofline:
             "roofline_fraction": self.roofline_fraction,
         }
 
-
-def annotate_bandwidth(sp, nbytes: int, seconds: float,
-                       device_kind: Optional[str] = None) -> float:
-    """Attach achieved GB/s to a trace span, plus its fraction of the HBM
-    peak when the device is in :data:`PEAKS` (no fraction for any other
-    device: a CPU run has no roof here). ``sp`` may be a null span (tracing
-    disabled) — ``annotate`` is then a no-op and only the return value
-    (GB/s) is meaningful. Returns 0.0 for degenerate timings instead of
-    raising."""
-    if seconds <= 0 or nbytes <= 0:
-        return 0.0
-    gbps = nbytes / seconds / 1e9
-    sp.annotate(achieved_gbps=round(gbps, 3))
-    peaks = device_peaks(device_kind)
-    if peaks is not None:
-        sp.annotate(frac_of_roof=round(gbps * 1e9 / peaks.hbm_bw, 6))
-    return gbps

@@ -50,23 +50,6 @@ def test_roofline_terms():
     assert r.useful_flops_ratio == 1.0
 
 
-def test_bandwidth_roof_only_for_listed_chips():
-    from repro.utils.roofline import annotate_bandwidth
-
-    class Sp:
-        def __init__(self):
-            self.attrs = {}
-
-        def annotate(self, **kw):
-            self.attrs.update(kw)
-
-    tpu, cpu = Sp(), Sp()
-    assert annotate_bandwidth(tpu, 819e9, 1.0, device_kind="TPU v5 lite") == 819.0
-    assert tpu.attrs == {"achieved_gbps": 819.0, "frac_of_roof": 1.0}
-    annotate_bandwidth(cpu, 819e9, 1.0, device_kind="cpu")
-    assert cpu.attrs == {"achieved_gbps": 819.0}
-
-
 MINI_DRYRUN = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

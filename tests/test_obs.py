@@ -138,6 +138,81 @@ def test_traced_decorator(recorder):
     assert ev["name"] == "deco.region" and ev["phase"] == "repair"
 
 
+def test_span_parent_links(recorder):
+    """Each event names the span that encloses it, so self time is its
+    duration less its children's."""
+    with trace.span("job"):
+        with trace.span("prep"):
+            with trace.span("sort"):
+                pass
+        with trace.span("run"):
+            pass
+    with trace.span("next"):
+        pass
+    evs = {e["name"]: e for e in recorder.events()}
+    assert len({e["id"] for e in evs.values()}) == 5
+    assert evs["job"]["parent"] is None and evs["next"]["parent"] is None
+    assert evs["prep"]["parent"] == evs["job"]["id"]
+    assert evs["run"]["parent"] == evs["job"]["id"]
+    assert evs["sort"]["parent"] == evs["prep"]["id"]
+    children = sum(e["dur_s"] for e in evs.values()
+                   if e["parent"] == evs["job"]["id"])
+    assert 0 <= evs["job"]["dur_s"] - children <= evs["job"]["dur_s"]
+
+
+def test_disabled_span_makes_no_profiler_call(monkeypatch):
+    """The recorder off: the shared null span, and no TraceAnnotation; on:
+    one annotation per span, entered and left around it."""
+    jax = pytest.importorskip("jax")
+    calls = []
+
+    class Annotation:
+        def __init__(self, name):
+            calls.append(("new", name))
+
+        def __enter__(self):
+            calls.append(("enter",))
+
+        def __exit__(self, *exc):
+            calls.append(("exit",))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    assert trace.span("a") is trace.span("b")
+    with trace.span("off", phase="build"):
+        pass
+    assert calls == []
+    rec = trace.get_recorder()
+    rec.start()
+    try:
+        with trace.span("on", phase="build"):
+            assert calls == [("new", "on"), ("enter",)]
+    finally:
+        rec.stop()
+        rec.clear()
+    assert calls[-1] == ("exit",)
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path, recorder):
+    """A recorded span is a host event of a profiler trace taken meanwhile,
+    on the device trace's clock."""
+    import glob
+
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("obs.profiled", phase="build") as sp:
+            sp.sync(jax.numpy.arange(64) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert "obs.profiled" in names
+
+
 # ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------

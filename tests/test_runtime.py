@@ -381,3 +381,182 @@ def test_backend_hooks_fixpoint_and_cascade():
     # shard_repair protocol: only capable backends implement it
     with pytest.raises(NotImplementedError):
         single.repair_plan_shards(gn, spec, xn, np.asarray(m), None, (0,))
+
+
+# ---------------------------------------------------------------------------
+# Sweep counters, job spans and named scopes
+# ---------------------------------------------------------------------------
+
+
+def test_sweep_counters_have_one_entry_per_round():
+    res = run(_graph(), 4, _spec(backend="single")).result
+    for counts in (res.cascade_iters, res.rebuild_iters):
+        assert counts.shape == (4,) and counts.dtype == np.int32
+    assert res.rebuilds.any() and not res.rebuilds.all()
+    assert (res.rebuild_iters[~res.rebuilds] == 0).all()
+    assert (res.rebuild_iters[res.rebuilds] > 0).all()
+    assert (res.cascade_iters > 0).all()
+
+
+def test_sweep_counters_warm_equal_cold():
+    g = _graph()
+    for backend, grid in (("single", dict()),
+                          ("serial", dict(mu_v=2, mu_s=2))):
+        sess = InfluenceSession(g, _spec(backend=backend, **grid))
+        cold = sess.find_seeds(4)
+        warm = sess.find_seeds_warm(4)
+        np.testing.assert_array_equal(warm.cascade_iters, cold.cascade_iters)
+        np.testing.assert_array_equal(warm.rebuild_iters, cold.rebuild_iters)
+
+
+def test_sweep_counters_match_a_plain_round_loop():
+    """Round by round, committing the job's seeds: the cascade and rebuild
+    fixpoints, called one at a time, take the sweeps the job reports."""
+    import jax.numpy as jnp
+
+    from repro.core import difuser
+    from repro.core.cascade import cascade_from_seed
+    from repro.core.simulate import propagate_to_fixpoint
+    from repro.core.sketch import count_visited
+    from repro.kernels import ops
+
+    g, k, spec = _graph(), 4, _spec(backend="single")
+    cfg = spec.difuser_config()
+    res = run(g, k, spec).result
+    gn, xn = difuser.normalize_inputs(g, cfg)
+    src, dst, h, lo, thr = difuser.edge_operands(gn, cfg)
+    x = jnp.asarray(xn)
+    kw = dict(seed=cfg.seed, impl=cfg.impl, edge_chunk=cfg.edge_chunk)
+    m, build, _ = difuser.build_sketch_matrix(gn, cfg, xn, normalized=True)
+    assert build == res.propagate_iters
+    oldscore = np.float32(0.0)
+    for i, s in enumerate(res.seeds):
+        m, casc = cascade_from_seed(m, int(s), src, dst, thr, x, h, lo,
+                                    max_iters=cfg.max_cascade_iters, **kw)
+        assert int(casc) == res.cascade_iters[i]
+        score = np.float32(int(count_visited(m, gn.n))) / np.float32(128)
+        rel = (score - oldscore) / np.maximum(score, np.float32(1e-9))
+        rebuilt = 0
+        if rel > cfg.rebuild_threshold:
+            m = ops.sketch_fill(m, reg_offset=0, seed=cfg.seed, impl=cfg.impl)
+            m, rebuilt = propagate_to_fixpoint(
+                m, src, dst, thr, x, h, lo,
+                max_iters=cfg.max_propagate_iters, **kw)
+            oldscore = score
+        assert int(rebuilt) == res.rebuild_iters[i]
+
+
+@pytest.mark.parametrize("strategy", ["block", "degree"])
+def test_serial_ring_counts_the_sweeps_single_does(strategy):
+    ref = _single_result("wc")
+    res = run(_graph(), 4, _spec(backend="serial", mu_v=2, mu_s=2,
+                                 partition=strategy)).result
+    assert res.propagate_iters == ref.propagate_iters
+    np.testing.assert_array_equal(res.cascade_iters, ref.cascade_iters)
+    np.testing.assert_array_equal(res.rebuild_iters, ref.rebuild_iters)
+
+
+MESH_COUNTERS = r"""
+import json
+import numpy as np
+from repro.graphs import rmat_graph
+from repro.obs import trace
+from repro.runtime import RunSpec, run
+
+g = rmat_graph(7, edge_factor=6, seed=9, setting="w1")
+spec = RunSpec(num_registers=128, seed=3)
+single = run(g, 4, spec.with_(backend="single")).result
+rec = trace.get_recorder()
+rec.start()
+mesh = run(g, 4, spec.with_(backend="mesh", mu_v=2, mu_s=2)).result
+rec.stop()
+print(json.dumps({
+    "single": [single.propagate_iters, single.cascade_iters.tolist(),
+               single.rebuild_iters.tolist()],
+    "mesh": [mesh.propagate_iters, mesh.cascade_iters.tolist(),
+             mesh.rebuild_iters.tolist()],
+    "counts": mesh.sweep_counts(),
+    "spans": [[e["name"], e["parent"], e["id"], e["attrs"]]
+              for e in rec.events()]}, default=str))
+"""
+
+
+def test_mesh_body_counts_the_sweeps_single_does():
+    """The shard_map body on four CPU devices returns the single driver's
+    counters, and its job span encloses the per-call partition."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parent.parent / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", MESH_COUNTERS], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["mesh"] == out["single"]
+    spans = {name: (parent, sid, attrs)
+             for name, parent, sid, attrs in out["spans"]}
+    job_parent, job_id, job_attrs = spans["mesh.find_seeds"]
+    assert job_parent is None
+    for name in ("partition.sample_edge_sets", "partition.plan",
+                 "partition.build_buckets"):
+        assert spans[name][0] == job_id, name
+    assert {k: job_attrs[k] for k in out["counts"]} == out["counts"]
+    assert job_attrs["compiles"] >= 1
+
+
+def test_job_span_carries_the_counters_and_compiles():
+    import jax
+
+    from repro.obs import metrics, trace
+
+    g = _graph()
+    # a register count no other test uses: the first job compiles
+    sess = InfluenceSession(g, RunSpec(num_registers=72, seed=3,
+                                       backend="single"))
+    before = metrics.counter("jit.compiles").value
+    jax.jit(lambda a: a * 3 + 1)(np.arange(5))
+    assert metrics.counter("jit.compiles").value == before + 1
+    rec = trace.get_recorder()
+    rec.start()
+    try:
+        first = sess.find_seeds(3)
+        second = sess.find_seeds(3)
+    finally:
+        rec.stop()
+        events = rec.events()
+        rec.clear()
+    jobs = [e for e in events if e["name"] == "single.find_seeds"]
+    assert len(jobs) == 2
+    for job, res in zip(jobs, (first, second)):
+        attrs = job["attrs"]
+        assert {k: attrs[k] for k in res.sweep_counts()} == res.sweep_counts()
+        kids = [e["name"] for e in events if e["parent"] == job["id"]]
+        assert kids == ["entry.normalize_inputs", "entry.edge_operands"]
+    assert jobs[0]["attrs"]["compiles"] >= 1
+    assert jobs[1]["attrs"]["compiles"] == 0
+
+
+def test_find_seeds_program_names_the_four_scopes():
+    import jax.numpy as jnp
+
+    from repro.core import difuser
+
+    g = _graph()
+    cfg = _spec().difuser_config()
+    gn, xn = difuser.normalize_inputs(g, cfg)
+    src, dst, h, lo, thr = difuser.edge_operands(gn, cfg)
+    text = difuser._find_seeds_jit.lower(
+        src, dst, h, lo, thr, jnp.asarray(xn), n_pad=gn.n_pad, k=2,
+        n_real=gn.n, num_regs=128, seed=cfg.seed, estimator=cfg.estimator,
+        impl=cfg.impl, edge_chunk=cfg.edge_chunk, max_prop=8, max_casc=8,
+        rebuild_threshold=cfg.rebuild_threshold,
+        predicate=difuser.resolve_model(cfg.model).predicate,
+    ).as_text(debug_info=True)
+    for scope in ("difuser.fixpoint", "difuser.select", "difuser.cascade",
+                  "difuser.rebuild"):
+        assert scope in text, scope

@@ -139,3 +139,29 @@ def test_find_seeds_fits_one_chip_at_rmat20(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one chip"
+
+
+def test_find_seeds_scatters_carry_their_phase_scope(one_chip):
+    """Each sweep's scatter-max in the chip's compiled job names exactly one
+    of the job's phases in its op metadata, and the first fixpoint, the
+    rebuild and the cascade each have theirs: a device trace can split the
+    job's time by phase."""
+    import re
+
+    n = 1 << 12
+    e = ((1 << 16,), jnp.int32)
+    u = ((1 << 16,), jnp.uint32)
+    args = _shapes(one_chip, e, e, u, u, u, ((J,), jnp.uint32))
+    text = _find_seeds_jit.lower(
+        *args, n_pad=n + 8, k=4, n_real=n, num_regs=J, seed=0,
+        estimator="hll", impl="ref", edge_chunk=2048, max_prop=64,
+        max_casc=64, rebuild_threshold=0.01,
+        predicate=resolve_model("wc").predicate).compile().as_text()
+    scopes = ("difuser.fixpoint", "difuser.select", "difuser.cascade",
+              "difuser.rebuild")
+    seen = set()
+    for name in re.findall(r'op_name="([^"]*scatter-max)"', text):
+        named = [s for s in scopes if s in name]
+        assert len(named) <= 1, name
+        seen.update(named)
+    assert seen == {"difuser.fixpoint", "difuser.cascade", "difuser.rebuild"}
