@@ -26,13 +26,18 @@ from repro.kernels import ops
 def cascade_from_seed(m, seed_vertex, src, dst, thr, x, h=None, lo=None, *,
                       seed: int = 0, impl: str = "ref", edge_chunk: int = 2048,
                       max_iters: int = 64, predicate=None,
-                      edge_block: int = 0, reg_tile: int = 0):
+                      edge_block: int = 0, reg_tile: int = 0, layout=None):
     """Mark the seed visited in all sims and close under sampled edges.
 
     Returns (m, iters_used). ``edge_chunk``/``edge_block``/``reg_tile`` are
     performance-only tile knobs (see core.simulate.propagate_to_fixpoint).
+    ``layout`` (ref impl): the arcs' ``ops.cascade_layout``; built here,
+    once for all sweeps, when omitted.
     """
     m = m.at[seed_vertex, :].set(jnp.int8(VISITED))
+    if impl == "ref" and layout is None:
+        layout = ops.cascade_layout(src, dst, thr, n_pad=m.shape[0], seed=seed,
+                                    edge_chunk=edge_chunk, h=h, lo=lo)
 
     def cond(carry):
         _, changed, it = carry
@@ -43,7 +48,7 @@ def cascade_from_seed(m, seed_vertex, src, dst, thr, x, h=None, lo=None, *,
         m_new = ops.cascade_sweep(m_cur, src, dst, thr, x, seed=seed, impl=impl,
                                   edge_chunk=edge_chunk, h=h, lo=lo,
                                   predicate=predicate, edge_block=edge_block,
-                                  reg_tile=reg_tile)
+                                  reg_tile=reg_tile, layout=layout)
         changed = jnp.any(m_new != m_cur)
         return m_new, changed, it + 1
 

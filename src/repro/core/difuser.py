@@ -83,11 +83,28 @@ class InfluenceResult:
                 "rebuild_sweeps": int(np.sum(self.rebuild_iters))}
 
 
-def annotate_job(sp, res: InfluenceResult, compiles0: int) -> None:
-    """Put ``res``'s sweep counters and the backend compiles since
+def annotate_job(sp, res: InfluenceResult, compiles0: int,
+                 **layout) -> None:
+    """Put ``res``'s sweep counters, the cascade layout's counters
+    ``layout`` (:func:`layout_stats`) and the backend compiles since
     ``compiles0`` (``jit.compiles``) on the job span ``sp``."""
-    sp.annotate(**res.sweep_counts(),
+    sp.annotate(**res.sweep_counts(), **layout,
                 compiles=metrics.counter("jit.compiles").value - compiles0)
+
+
+def layout_stats(layout) -> dict:
+    """The job's counters of the cascade's pull layout (none without one):
+    ``cascade_pad_share``, dead slots over the slots of the pieces, and
+    ``cascade_split_vertices``, vertices whose pieces are merged."""
+    if layout is None:
+        return {}
+    return {"cascade_pad_share": layout.pad_share,
+            "cascade_split_vertices": layout.split_vertices}
+
+
+def _host_stats(stats: dict) -> dict:
+    """:func:`layout_stats` fetched to Python numbers."""
+    return {k: v.item() for k, v in jax.device_get(stats).items()}
 
 
 def edge_operands(g: Graph, cfg: DiFuserConfig):
@@ -132,8 +149,16 @@ def _seed_rounds(m, src, dst, h, lo, thr, x, *, k, n_real, num_regs, seed,
 
     The round's phases sit under the named scopes ``difuser.select``,
     ``difuser.cascade`` and ``difuser.rebuild`` (op metadata only), and each
-    round also returns its cascade and rebuild sweep counts.
+    round also returns its cascade and rebuild sweep counts. The cascade's
+    pull layout is built once, before the rounds; its counters
+    (:func:`layout_stats`) come back beside the rounds' outputs.
     """
+    layout = None
+    if impl == "ref":
+        with jax.named_scope("difuser.cascade"):
+            layout = ops.cascade_layout(
+                src, dst, thr, n_pad=m.shape[0], seed=seed,
+                edge_chunk=cascade_chunk or edge_chunk, h=h, lo=lo)
 
     def round_fn(carry, _):
         m, score, oldscore = carry
@@ -145,7 +170,8 @@ def _seed_rounds(m, src, dst, h, lo, thr, x, *, k, n_real, num_regs, seed,
             m, casc_iters = cascade_from_seed(
                 m, s, src, dst, thr, x, h, lo, seed=seed, impl=impl,
                 edge_chunk=cascade_chunk or edge_chunk, max_iters=max_casc,
-                predicate=predicate, edge_block=edge_block, reg_tile=reg_tile)
+                predicate=predicate, edge_block=edge_block, reg_tile=reg_tile,
+                layout=layout)
             visited = count_visited(m, n_real).astype(jnp.float32)
         new_score = visited / jnp.float32(num_regs)
         rel = (new_score - oldscore) / jnp.maximum(new_score, 1e-9)
@@ -170,8 +196,8 @@ def _seed_rounds(m, src, dst, h, lo, thr, x, *, k, n_real, num_regs, seed,
 
     (_, _, _), outs = jax.lax.scan(round_fn, (m, jnp.float32(0.0), jnp.float32(0.0)),
                                    None, length=k)
-    # (seeds, gains, scores, rebuilds, cascade_iters, rebuild_iters)
-    return outs
+    # (seeds, gains, scores, rebuilds, cascade_iters, rebuild_iters), stats
+    return outs, layout_stats(layout)
 
 
 def _build_matrix(src, dst, h, lo, thr, x, n_pad, *, n_real, num_regs, seed, impl,
@@ -196,14 +222,13 @@ def _find_seeds(src, dst, h, lo, thr, x, n_pad, *, k, n_real, num_regs, seed,
         src, dst, h, lo, thr, x, n_pad, n_real=n_real, num_regs=num_regs,
         seed=seed, impl=impl, edge_chunk=edge_chunk, max_prop=max_prop,
         predicate=predicate, edge_block=edge_block, reg_tile=reg_tile)
-    seeds, gains, scores, rebuilds, casc_iters, rebuild_iters = _seed_rounds(
+    rounds, stats = _seed_rounds(
         m, src, dst, h, lo, thr, x, k=k, n_real=n_real, num_regs=num_regs,
         seed=seed, estimator=estimator, impl=impl, edge_chunk=edge_chunk,
         max_prop=max_prop, max_casc=max_casc,
         rebuild_threshold=rebuild_threshold, predicate=predicate,
         cascade_chunk=cascade_chunk, edge_block=edge_block, reg_tile=reg_tile)
-    return (seeds, gains, scores, rebuilds, build_iters, casc_iters,
-            rebuild_iters)
+    return rounds, build_iters, stats
 
 
 #: the performance-only tile statics shared by the jitted drivers
@@ -250,12 +275,12 @@ def _find_seeds_single(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
             predicate=resolve_model(cfg.model).predicate,
             cascade_chunk=cfg.cascade_chunk, edge_block=cfg.edge_block,
             reg_tile=cfg.reg_tile))
-        seeds, gains, scores, rebuilds, build_iters, casc, reb = out
+        (seeds, gains, scores, rebuilds, casc, reb), build_iters, stats = out
         res = InfluenceResult(
             seeds=seeds, est_gains=gains, scores=scores, rebuilds=rebuilds,
             propagate_iters=int(build_iters), x=np.asarray(x),
             cascade_iters=casc, rebuild_iters=reb)
-        annotate_job(sp, res, compiles0)
+        annotate_job(sp, res, compiles0, **_host_stats(stats))
     return res
 
 
@@ -360,7 +385,7 @@ def find_seeds_warm(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
     src, dst, h, lo, thr = edges
     with trace.span("single.warm_rounds", phase="select", k=k, n=g.n,
                     registers=int(x.shape[0])) as sp:
-        seeds, gains, scores, rebuilds, casc, reb = sp.sync(_seed_rounds_jit(
+        rounds, stats = sp.sync(_seed_rounds_jit(
             matrix, src, dst, h, lo, thr,
             jnp.asarray(x), k=k, n_real=g.n, num_regs=x.shape[0], seed=cfg.seed,
             estimator=cfg.estimator, impl=cfg.impl, edge_chunk=cfg.edge_chunk,
@@ -369,8 +394,9 @@ def find_seeds_warm(g: Graph, k: int, config: Optional[DiFuserConfig] = None,
             predicate=resolve_model(cfg.model).predicate,
             cascade_chunk=cfg.cascade_chunk, edge_block=cfg.edge_block,
             reg_tile=cfg.reg_tile))
+        sp.annotate(**_host_stats(stats))
+    seeds, gains, scores, rebuilds, casc, reb = jax.device_get(rounds)
     return InfluenceResult(
-        seeds=np.asarray(seeds), est_gains=np.asarray(gains),
-        scores=np.asarray(scores), rebuilds=np.asarray(rebuilds),
-        propagate_iters=0, x=np.asarray(x), cascade_iters=np.asarray(casc),
-        rebuild_iters=np.asarray(reb))
+        seeds=seeds, est_gains=gains, scores=scores, rebuilds=rebuilds,
+        propagate_iters=0, x=np.asarray(x), cascade_iters=casc,
+        rebuild_iters=reb)

@@ -77,13 +77,27 @@ def fused_sweep(m, src, dst, thr, x, *, num_sweeps: int = 1, seed: int = 0,
                               lane_tile=lane_fill or reg_tile or REG_TILE)
 
 
+def cascade_layout(src, dst, thr, *, n_pad: int, seed: int = 0,
+                   edge_chunk: int = 2048, h=None, lo=None):
+    """The ref cascade sweep's pull layout of the arcs (any order), with
+    chunks of ``edge_chunk`` slots; see ``kernels/ref.py::cascade_layout``."""
+    h, lo, _ = _ref.edge_args(src, dst, thr, h, lo, None, seed)
+    return _ref.cascade_layout(src, dst, thr, h, lo, n_pad=n_pad,
+                               chunk=edge_chunk)
+
+
 def cascade_sweep(m, src, dst, thr, x, *, seed: int = 0, impl: str = "ref",
                   edge_chunk: int = 2048, h=None, lo=None, predicate=None,
-                  edge_block: int = 0, reg_tile: int = 0):
+                  edge_block: int = 0, reg_tile: int = 0, layout=None):
+    """One cascade sweep over edges in any order. ``layout`` (ref impl):
+    the arcs' :func:`cascade_layout`, built once by a caller that sweeps
+    many times and then the sweep's only edge input (the arcs are not
+    read); built here from the arcs when omitted."""
     if impl == "ref":
-        return _ref.cascade_sweep_ref(
-            m, src, dst, thr, x, h, lo, seed=seed, predicate=predicate,
-            edge_chunk=edge_chunk)
+        if layout is None:
+            layout = cascade_layout(src, dst, thr, n_pad=m.shape[0], seed=seed,
+                                    edge_chunk=edge_chunk, h=h, lo=lo)
+        return _ref.cascade_sweep_ref(m, layout, x, predicate=predicate)
     return cascade_sweep_pallas(m, src, dst, thr, x, h, lo, seed=seed,
                                 predicate=predicate,
                                 edge_block=edge_block or EDGE_BLOCK,

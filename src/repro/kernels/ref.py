@@ -20,13 +20,14 @@ dst, seed), lo = 0, and the predicate collapses to ``(X ^ h) < thr``.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.sampling import edge_hash, fused_predicate
 from repro.core.sketch import C_HARMONIC, VISITED
-from repro.kernels.common import clamp_block, pad_amount
+from repro.kernels.common import clamp_block, pad_amount, round_up
 
 
 def _chunked(src, dst, h, lo, thr, edge_chunk: int):
@@ -158,29 +159,118 @@ def fused_sweep_ref(m: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
     return out
 
 
-@partial(jax.jit, static_argnames=("edge_chunk", "seed", "predicate"))
-def cascade_sweep_ref(m: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
-                      thr: jnp.ndarray, x: jnp.ndarray, h=None, lo=None, *,
-                      edge_chunk: int = 2048, seed: int = 0,
-                      predicate=None) -> jnp.ndarray:
+#: slots per piece of the cascade's pull layout (chosen on a v5e, PERF.md)
+CASCADE_SLOT_WIDTH = 8
+
+
+class CascadeLayout(NamedTuple):
+    """The cascade sweep's pull layout: every vertex's in-arcs cut into
+    pieces of ``CASCADE_SLOT_WIDTH`` slots, pieces in destination order, the
+    tail piece of each vertex padded with dead slots (``thr = 0``, which
+    never fires). Every vertex owns at least one piece, so the pieces of one
+    chunk belong to at most as many consecutive vertices as the chunk has
+    pieces. The arrays are sized by the bound ``ceil(m / W) + n_pad``
+    pieces, whatever the degrees: graphs of equal (n_pad, m) share one
+    program. Depends only on the arcs."""
+
+    src: jnp.ndarray             # int32[chunks, C] slot's arc source
+    h: jnp.ndarray               # uint32[chunks, C]
+    lo: jnp.ndarray              # uint32[chunks, C]
+    thr: jnp.ndarray             # uint32[chunks, C] 0 on dead slots
+    owner: jnp.ndarray           # int32[chunks, C // W] piece's destination
+    chunks: jnp.ndarray          # int32[] leading chunks that hold pieces
+    pad_share: jnp.ndarray       # float32[] dead slots / slots of the pieces
+    split_vertices: jnp.ndarray  # int32[] vertices with more than W arcs
+
+
+@partial(jax.jit, static_argnames=("n_pad", "chunk"))
+def cascade_layout(src, dst, thr, h, lo, *, n_pad: int,
+                   chunk: int) -> CascadeLayout:
+    """Build the :class:`CascadeLayout` of the arcs on the device. The arcs
+    may come in any order. ``chunk`` is the sweep's scan chunk in slots,
+    rounded up to whole pieces."""
+    w = CASCADE_SLOT_WIDTH
+    num_edges = src.shape[0]
+    bound = -(-num_edges // w) + n_pad
+    chunk = round_up(clamp_block(bound * w, chunk), w)
+    per_chunk = chunk // w
+    num_chunks = -(-bound // per_chunk)
+
+    dst = dst.astype(jnp.int32)
+    order = jnp.argsort(dst)
+    dst = dst[order]
+    start = jnp.searchsorted(dst, jnp.arange(n_pad + 1, dtype=jnp.int32),
+                             method="sort")
+    deg = jnp.diff(start)
+    pieces = jnp.maximum(1, -(-deg // w))
+    poff = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(pieces)])
+    total = poff[n_pad]
+    first = poff[:n_pad] * w      # vertex v owns slots [first[v], first[v+1])
+
+    def per_owner(marks, size, vals):
+        # vals[v] on every slot or piece v owns: every vertex owns at least
+        # one, so the marks are distinct and a running sum of steps is exact
+        steps = jnp.diff(vals, prepend=jnp.zeros(1, vals.dtype))
+        return jnp.cumsum(jnp.zeros(size, vals.dtype).at[marks].add(steps))
+
+    # everything stays one-dimensional: a (pieces, W) array would be padded
+    # to whole lane tiles on a TPU
+    vertices = jnp.arange(n_pad, dtype=jnp.int32)
+    owner = per_owner(poff[:n_pad], num_chunks * per_chunk, vertices)
+    arc = (jnp.arange(num_chunks * chunk, dtype=jnp.int32)
+           + per_owner(first, num_chunks * chunk, start[:n_pad] - first))
+    # slots past a vertex's last arc (and past the last piece) are dead
+    valid = arc < per_owner(first, num_chunks * chunk, start[1:])
+    arc = order[jnp.minimum(arc, num_edges - 1)]
+
+    def slots(a):
+        return jnp.where(valid, a[arc], 0).astype(a.dtype).reshape(
+            num_chunks, chunk)
+
+    return CascadeLayout(
+        src=slots(src), h=slots(h), lo=slots(lo), thr=slots(thr),
+        owner=owner.reshape(num_chunks, per_chunk),
+        chunks=-(-total // per_chunk),
+        pad_share=1.0 - jnp.float32(num_edges) / (total * w).astype(jnp.float32),
+        split_vertices=jnp.sum(deg > w, dtype=jnp.int32))
+
+
+@partial(jax.jit, static_argnames=("predicate",))
+def cascade_sweep_ref(m: jnp.ndarray, layout: CascadeLayout, x: jnp.ndarray,
+                      *, predicate=None) -> jnp.ndarray:
     """One CASCADE sweep (paper Alg. 3): propagate visitedness forward.
 
     For every edge (u, v) live in sim j with M[u, j] == VISITED, mark
     M[v, j] <- VISITED. Jacobi semantics as above.
+
+    A pull over ``layout``, the sweep's only edge input: each chunk gathers
+    its slots' source rows and ORs them into the rows of the consecutive
+    vertices that own them, through one product of a (vertices x slots)
+    one-hot with the chunk's live mask, and one contiguous read-modify-write
+    of those rows. No per-arc scatter.
     """
-    h, lo, predicate = edge_args(src, dst, thr, h, lo, predicate, seed)
-    xs, _ = _chunked(src, dst, h, lo, thr, edge_chunk)
+    predicate = predicate or fused_predicate
+    n_pad, _ = m.shape
+    per_chunk = layout.owner.shape[1]
+    w = layout.src.shape[1] // per_chunk
+    rows = min(per_chunk, n_pad)
     vis = m == VISITED
 
-    def body(acc, chunk):
-        s, d, hh, ll, t = chunk
-        mask = _edge_mask(hh, ll, t, x, predicate)
-        newly = jnp.logical_and(mask, vis[s]).astype(jnp.uint8)
-        acc = acc.at[d].max(newly)
-        return acc, None
+    def body(k, acc):
+        mask = _edge_mask(layout.h[k], layout.lo[k], layout.thr[k], x, predicate)
+        live = jnp.logical_and(mask, vis[layout.src[k]])
+        owner = layout.owner[k]
+        v0 = jnp.minimum(owner[0], n_pad - rows)
+        onehot = (jnp.repeat(owner - v0, w)[None, :]
+                  == jnp.arange(rows, dtype=jnp.int32)[:, None])
+        # counts of live slots, exact in float32: > 0 is their OR
+        pulled = jnp.dot(onehot.astype(jnp.bfloat16), live.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32) > 0
+        cur = jax.lax.dynamic_slice_in_dim(acc, v0, rows)
+        return jax.lax.dynamic_update_slice_in_dim(acc, cur | pulled, v0, 0)
 
-    acc, _ = jax.lax.scan(body, vis.astype(jnp.uint8), xs)
-    return jnp.where(acc.astype(bool), jnp.int8(VISITED), m)
+    acc = jax.lax.fori_loop(0, layout.chunks, body, vis)
+    return jnp.where(acc, jnp.int8(VISITED), m)
 
 
 def cardinality_stats_ref(m: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -165,8 +165,13 @@ def measure_sweep_family(g, spec, family: str, *,
             call = jax.jit(lambda m_, h_, lo_: ops.propagate_sweep(
                 m_, src, dst, thr, xj, h=h_, lo=lo_, **kw))
         elif family == "cascade_step":
+            # the jobs build the pull layout once and sweep it many times:
+            # time the sweep, not the build
+            layout = None if cfg.impl != "ref" else ops.cascade_layout(
+                src, dst, thr, n_pad=m.shape[0], seed=cfg.seed,
+                edge_chunk=chunk, h=h, lo=lo)
             call = jax.jit(lambda m_, h_, lo_: ops.cascade_sweep(
-                m_, src, dst, thr, xj, h=h_, lo=lo_, **kw))
+                m_, src, dst, thr, xj, h=h_, lo=lo_, layout=layout, **kw))
         elif family == "fused_sample":   # no scan chunk — tiles only
             kw.pop("edge_chunk")
             call = jax.jit(lambda m_, h_, lo_: ops.fused_sample(

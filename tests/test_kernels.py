@@ -8,6 +8,7 @@ import pytest
 from repro.core.sampling import make_x_vector, weight_to_threshold
 from repro.graphs import rmat_graph
 from repro.kernels import ops
+from repro.kernels.ref import CASCADE_SLOT_WIDTH as W
 
 
 def _arrays(scale, ef, regs, setting="u01", seed=0):
@@ -84,3 +85,33 @@ def test_propagate_padding_edges_inert():
     if pad_src.shape[0]:
         out2 = ops.propagate_sweep(m, pad_src, pad_dst, pad_thr, x, impl="ref")
         np.testing.assert_array_equal(np.asarray(out2), np.asarray(m))
+
+
+MODELS = ["wc", "ic:0.2", "lt", "dic:0.5"]
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_cascade_pull_sweep_equals_scatter_oracle(pull_case, model,
+                                                  pull_operands,
+                                                  scatter_cascade_sweep):
+    """The ref cascade sweep (a pull over destination-grouped slots) equals
+    the per-arc scatter-max bit for bit, sweep after sweep, with the layout
+    built by the sweep itself and built once by the caller."""
+    n_pad, src, dst, h, lo, thr, predicate, chunk = pull_operands(pull_case,
+                                                                  model)
+    kw = dict(h=h, lo=lo, predicate=predicate, edge_chunk=chunk)
+    layout = ops.cascade_layout(src, dst, thr, n_pad=n_pad, edge_chunk=chunk,
+                                h=h, lo=lo)
+    if pull_case == "ragged_chunk":
+        per_chunk = layout.owner.shape[1]
+        assert (-(-src.shape[0] // W) + n_pad) % per_chunk
+    x = jnp.asarray(make_x_vector(128, seed=3))
+    m = ops.sketch_fill(jnp.zeros((n_pad, 128), jnp.int8), impl="ref")
+    m = m.at[jnp.array([0, 3, n_pad // 2])].set(-1)
+    for _ in range(3):
+        want = np.asarray(scatter_cascade_sweep(m, src, dst, thr, x, **kw))
+        got = ops.cascade_sweep(m, src, dst, thr, x, **kw)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_array_equal(np.asarray(ops.cascade_sweep(
+            m, src, dst, thr, x, layout=layout, **kw)), want)
+        m = got

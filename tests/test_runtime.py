@@ -560,3 +560,78 @@ def test_find_seeds_program_names_the_four_scopes():
     for scope in ("difuser.fixpoint", "difuser.select", "difuser.cascade",
                   "difuser.rebuild"):
         assert scope in text, scope
+
+
+def _star_twin(g):
+    """The bench harness's warm-up graph: the same (n, m), every real arc
+    leaving vertex 0 with probability 1."""
+    import dataclasses
+
+    real = np.arange(g.m) < g.m_real
+    return dataclasses.replace(
+        g, src=np.where(real, 0, g.src).astype(g.src.dtype),
+        dst=np.where(real, 1 + np.arange(g.m) % (g.n - 1),
+                     g.dst).astype(g.dst.dtype),
+        weight=np.where(real, 1.0, 0.0).astype(np.float32))
+
+
+def test_find_seeds_lowers_to_one_program_for_a_graph_and_its_star_twin():
+    """The job program depends on (n_pad, m) alone, not on the degrees the
+    cascade's pull layout groups: a Kronecker graph and its star twin lower
+    to the same program, so a warm-up on the twin leaves nothing to compile."""
+    import jax.numpy as jnp
+
+    from repro.core import difuser
+
+    kron = rmat_graph(8, edge_factor=8, seed=4, setting="w1")
+    cfg = _spec().difuser_config()
+    texts, splits = [], []
+    for g in (kron, _star_twin(kron)):
+        gn, xn = difuser.normalize_inputs(g, cfg)
+        src, dst, h, lo, thr = difuser.edge_operands(gn, cfg)
+        texts.append(difuser._find_seeds_jit.lower(
+            src, dst, h, lo, thr, jnp.asarray(xn), n_pad=gn.n_pad, k=2,
+            n_real=gn.n, num_regs=128, seed=cfg.seed,
+            estimator=cfg.estimator, impl=cfg.impl,
+            edge_chunk=cfg.edge_chunk, max_prop=8, max_casc=8,
+            rebuild_threshold=cfg.rebuild_threshold,
+            predicate=difuser.resolve_model(cfg.model).predicate).as_text())
+        splits.append(int(difuser.ops.cascade_layout(
+            src, dst, thr, n_pad=gn.n_pad, h=h, lo=lo).split_vertices))
+    assert splits[0] != splits[1]     # the two layouts group differently
+    assert texts[0] == texts[1]
+
+
+def test_cascade_layout_counters_equal_their_hand_count():
+    """On a hand-built graph the job span and the warm rounds' span carry
+    the pull layout's counters as counted by hand: vertices 1-4 have W,
+    W + 1 and 2W + 3 in-arcs beside one of 1, so they take 1, 1, 2 and 3
+    pieces of W slots, every other vertex one dead piece, and two of them
+    are split."""
+    from repro.kernels.ref import CASCADE_SLOT_WIDTH as W
+    from repro.obs import trace
+
+    degrees = (1, W, W + 1, 2 * W + 3)
+    src = np.concatenate([np.arange(5, 5 + d) for d in degrees])
+    dst = np.repeat([1, 2, 3, 4], degrees)
+    g = Graph.from_edges(2 * W + 8, src, dst,
+                         np.full(src.size, 0.5, np.float32), edge_block=1)
+    assert g.m == sum(degrees)        # no padded arcs
+    pieces = g.n_pad - 4 + 1 + 1 + 2 + 3
+    want = {"cascade_pad_share": 1.0 - sum(degrees) / (pieces * W),
+            "cascade_split_vertices": 2}
+    sess = InfluenceSession(g, _spec(backend="single"))
+    rec = trace.get_recorder()
+    rec.start()
+    try:
+        sess.find_seeds(2)
+        sess.find_seeds_warm(2)
+    finally:
+        rec.stop()
+        events = rec.events()
+        rec.clear()
+    spans = {e["name"]: e["attrs"] for e in events}
+    for got in (spans["single.find_seeds"], spans["single.warm_rounds"]):
+        assert got["cascade_split_vertices"] == want["cascade_split_vertices"]
+        assert got["cascade_pad_share"] == pytest.approx(
+            want["cascade_pad_share"], rel=1e-6)

@@ -7,6 +7,7 @@ hash/X values, BFS the reachability sets in python, and check:
 """
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.cascade import cascade_from_seed
 from repro.core.sampling import (clz32, edge_hash, make_x_vector,
@@ -95,3 +96,23 @@ def test_cascade_monotone_scores(small_graph):
         cur = int((np.asarray(m[: g.n]) == -1).sum())
         assert cur >= prev
         prev = cur
+
+
+@pytest.mark.parametrize("model", ["wc", "ic:0.2", "lt", "dic:0.5"])
+def test_cascade_from_seed_equals_scatter_oracle(pull_case, model,
+                                                 pull_operands,
+                                                 scatter_cascade_fixpoint):
+    """The cascade fixpoint over the pull layout gives the scatter oracle's
+    registers bit for bit, in as many sweeps, seed after seed."""
+    n_pad, src, dst, h, lo, thr, predicate, chunk = pull_operands(pull_case,
+                                                                  model)
+    kw = dict(predicate=predicate, edge_chunk=chunk)
+    x = jnp.asarray(make_x_vector(64, seed=24))
+    m = want = ops.sketch_fill(jnp.zeros((n_pad, 64), jnp.int8))
+    for s in (0, 5, n_pad // 3):
+        want, want_iters = scatter_cascade_fixpoint(
+            want, jnp.int32(s), src, dst, thr, x, h, lo, **kw)
+        m, iters = cascade_from_seed(m, jnp.int32(s), src, dst, thr, x, h, lo,
+                                     **kw)
+        np.testing.assert_array_equal(np.asarray(m), np.asarray(want))
+        assert int(iters) == want_iters

@@ -143,25 +143,44 @@ def test_find_seeds_fits_one_chip_at_rmat20(one_chip):
 
 def test_find_seeds_scatters_carry_their_phase_scope(one_chip):
     """Each sweep's scatter-max in the chip's compiled job names exactly one
-    of the job's phases in its op metadata, and the first fixpoint, the
-    rebuild and the cascade each have theirs: a device trace can split the
-    job's time by phase."""
+    of the job's phases in its op metadata, and the first fixpoint and the
+    rebuild each have theirs: a device trace can split the job's time by
+    phase. The cascade has no scatter on the register shape: its sweep pulls
+    through a product and one window write per chunk, and those ops carry
+    ``difuser.cascade``."""
     import re
 
     n = 1 << 12
+    n_pad = n + 8
     e = ((1 << 16,), jnp.int32)
     u = ((1 << 16,), jnp.uint32)
     args = _shapes(one_chip, e, e, u, u, u, ((J,), jnp.uint32))
     text = _find_seeds_jit.lower(
-        *args, n_pad=n + 8, k=4, n_real=n, num_regs=J, seed=0,
+        *args, n_pad=n_pad, k=4, n_real=n, num_regs=J, seed=0,
         estimator="hll", impl="ref", edge_chunk=2048, max_prop=64,
         max_casc=64, rebuild_threshold=0.01,
         predicate=resolve_model("wc").predicate).compile().as_text()
     scopes = ("difuser.fixpoint", "difuser.select", "difuser.cascade",
               "difuser.rebuild")
+    ops = []   # (dims, opcode, op_name) of every instruction with metadata
+    for line in text.splitlines():
+        instr = re.search(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if instr and name:
+            ops.append((instr.group(1), instr.group(2), name.group(1)))
     seen = set()
-    for name in re.findall(r'op_name="([^"]*scatter-max)"', text):
-        named = [s for s in scopes if s in name]
-        assert len(named) <= 1, name
-        seen.update(named)
-    assert seen == {"difuser.fixpoint", "difuser.cascade", "difuser.rebuild"}
+    for _, _, name in ops:
+        if name.endswith("scatter-max"):
+            named = [s for s in scopes if s in name]
+            assert len(named) <= 1, name
+            seen.update(named)
+    assert seen == {"difuser.fixpoint", "difuser.rebuild"}
+    register = f"{n_pad},{J}"
+    cascade = [(dims, opcode, name) for dims, opcode, name in ops
+               if "difuser.cascade" in name]
+    assert not [op for op in cascade
+                if op[0] == register and "scatter" in op[1]]
+    sweep = [op for op in cascade if "cascade_sweep_ref" in op[2]]
+    assert any(name.endswith("dot_general") for _, _, name in sweep)
+    assert any(dims == register and name.endswith("dynamic_update_slice")
+               for dims, _, name in sweep)
