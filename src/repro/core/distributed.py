@@ -22,7 +22,12 @@ The host-side partition build lives in :mod:`repro.partition`: a
 ``PartitionPlan`` (``DistributedConfig.partition`` selects the strategy —
 ``block`` is the historical contiguous split, ``degree``/``edge`` balance
 the per-shard work via a vertex relabeling permutation) feeds
-``build_partition_2d``, which emits per-ring-step bucket arrays. The body
+``build_partition_2d``, which emits per-ring-step bucket arrays. The job
+and the build run on a partition fixed by the graph: each sim shard takes
+every edge live for some value of its fixed range of X values
+(``fasst.shard_value_ranges``), so one partition, placed on the mesh once,
+and one program per shape serve every X inside the ranges; an X outside
+them, or a model without a range test, builds its own. The body
 here stays plan-agnostic: it sweeps whatever buckets it is handed, and the
 ``owned_ids`` array (local row -> original vertex id) keeps register
 hashes, validity masks, and the reported seeds in original-id space — so
@@ -40,27 +45,33 @@ model's predicate).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import itertools
 import math
+import weakref
 from time import perf_counter
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import sketch
 from repro.core.difuser import (DiFuserConfig, InfluenceResult, annotate_job,
                                 resolve_model)
-from repro.core.sampling import fused_predicate
-from repro.core.sampling import make_x_vector
+from repro.core.fasst import in_ranges, partition_samples
+from repro.core.sampling import fused_predicate, make_x_vector
 from repro.core.sketch import VISITED
 from repro.graphs.structs import Graph
 from repro.obs import metrics, shardprof, trace
 # host-side partition build moved to repro.partition; re-exported here for
 # backward compatibility (tests and dryrun historically imported from core)
 from repro.partition import (Partition2D, build_partition_2d,  # noqa: F401
-                             plan_partition, sample_edge_sets)
+                             plan_partition, sample_edge_sets,
+                             sample_edge_sets_in_ranges)
 
 # ---------------------------------------------------------------------------
 # Device-side shard_map body
@@ -344,49 +355,167 @@ def _sample_edge_sets(g: Graph, x: np.ndarray, mu_s: int,
                                 method=method), method
 
 
-def _find_seeds_distributed(g: Graph, k: int, mesh,
-                            config: Optional[DistributedConfig] = None,
-                            x: Optional[np.ndarray] = None, plan=None):
-    """shard_map Alg. 4 driver (the ``mesh`` runtime backend's body).
-    Returns (InfluenceResult, Partition2D).
-
-    Seeds/estimates come back in original vertex ids for every
-    ``cfg.partition`` strategy (the relabeling is un-permuted on device via
-    ``owned_ids``). ``plan`` overrides the ``cfg.partition``-derived
-    :class:`PartitionPlan` (results are plan-invariant either way). The
-    ``mesh.find_seeds`` span covers the whole call, the per-call partition
-    included, and carries the job's sweep counters and compiles.
-    """
-    cfg = config or DistributedConfig()
-    mu_v = mesh.shape[cfg.vertex_axis]
-    mu_s = math.prod(mesh.shape[ax] for ax in cfg.sim_axes)
-    compiles0 = metrics.counter("jit.compiles").value
-    with trace.span("mesh.find_seeds", phase="select", k=k, mu_v=mu_v,
-                    mu_s=mu_s, schedule=cfg.schedule) as sp:
-        res, part = _run_distributed(g, k, mesh, cfg, x, plan, mu_v, mu_s)
-        annotate_job(sp, res, compiles0)
-    return res, part
-
-
-def _run_distributed(g: Graph, k: int, mesh, cfg: DistributedConfig, x,
-                     plan, mu_v: int, mu_s: int):
-    """The body of :func:`_find_seeds_distributed`, inside its job span."""
-    from jax.sharding import PartitionSpec as P
-
-    if x is None:
-        x = make_x_vector(cfg.num_registers, seed=cfg.seed)
-    g = g.sorted_by_dst()
-    # the O(m * mu_s) sampled-edge preprocessing feeds both the planner and
-    # the bucket build — run it once
-    sampled, method = _sample_edge_sets(g, x, mu_s, cfg)
+def _partition_for_x(gs: Graph, x: np.ndarray, mu_v: int, mu_s: int,
+                     cfg: DistributedConfig, plan=None) -> Partition2D:
+    """The per-X partition of dst-sorted ``gs``: the edge sets ``x``'s own
+    FASST chunks sample, planned (unless ``plan`` is given) and bucketed
+    for this call alone."""
+    sampled, method = _sample_edge_sets(gs, x, mu_s, cfg)
     if plan is None:
-        plan = plan_partition(g, mu_v, mu_s=mu_s, strategy=cfg.partition,
+        plan = plan_partition(gs, mu_v, mu_s=mu_s, strategy=cfg.partition,
                               seed=cfg.seed, model=cfg.model, method=method,
                               sampled=sampled)
-    part = build_partition_2d(g, x, mu_v, mu_s, seed=cfg.seed, method=method,
+    metrics.counter("mesh.partitions_built").inc()
+    return build_partition_2d(gs, x, mu_v, mu_s, seed=cfg.seed, method=method,
                               model=cfg.model, plan=plan, pad_mode=cfg.pad_mode,
                               sampled=sampled)
 
+
+def _bucket_arrays(part: Partition2D) -> list:
+    """The bucket arrays in the bodies' argument order: ten fields (the
+    propagate five, then the cascade five), each one array per ring step."""
+    return [step for field in (part.p_h, part.p_w, part.p_r, part.p_t,
+                               part.p_l, part.c_h, part.c_w, part.c_r,
+                               part.c_t, part.c_l) for step in field]
+
+
+@dataclasses.dataclass
+class _MeshPartition:
+    """The partition of one (graph, config, mesh, register count), fixed by
+    the graph: the sim shards' value ranges, their edge sets bucketed once
+    and placed on the mesh, and the jitted programs built on its shapes."""
+
+    graph: Graph                 # the dst-sorted graph it was built from
+    part: Partition2D            # x_shards of the call that built it
+    ranges: np.ndarray           # uint32[mu_s, 2]
+    owned: jax.Array             # owned_ids, placed on the mesh
+    buckets: tuple               # _bucket_arrays, placed on the mesh
+    programs: dict = dataclasses.field(default_factory=dict)
+
+    def program(self, key, make):
+        if key not in self.programs:
+            self.programs[key] = make()
+        return self.programs[key]
+
+
+def _same_graph(a: Graph, b: Graph) -> bool:
+    return a is b or (a.n == b.n and a.n_pad == b.n_pad
+                      and a.m_real == b.m_real and a.m == b.m
+                      and np.array_equal(a.dst, b.dst)
+                      and np.array_equal(a.src, b.src)
+                      and np.array_equal(a.weight, b.weight))
+
+
+class PartitionCache:
+    """The mesh path's per-graph partitions (the ``mesh`` backend holds
+    one). An entry is found by the graph object it was built for, or by a
+    graph equal to its dst-sorted one (the build path is handed a fresh
+    sorted copy), and is dropped with that graph object, or past ``SIZE``
+    entries, oldest first."""
+
+    SIZE = 4
+
+    def __init__(self):
+        self._tokens = itertools.count()
+        self._entries: "collections.OrderedDict[int, tuple]" = (
+            collections.OrderedDict())
+
+    def find(self, g: Graph, key) -> Optional[_MeshPartition]:
+        for token, (k, source, entry) in self._entries.items():
+            if k == key and (source() is g or _same_graph(g, entry.graph)):
+                self._entries.move_to_end(token)
+                return entry
+        return None
+
+    def add(self, g: Graph, key, entry: _MeshPartition) -> None:
+        token = next(self._tokens)
+        self._entries[token] = (key, weakref.ref(g), entry)
+        weakref.finalize(g, self._entries.pop, token, None)
+        while len(self._entries) > self.SIZE:
+            self._entries.popitem(last=False)
+
+
+def _fixed_partition(g: Graph, mesh, cfg: DistributedConfig, x: np.ndarray,
+                     mu_v: int, mu_s: int, partitions: PartitionCache):
+    """The partition of (``g``, ``cfg``, ``mesh``, ``len(x)``) fixed by the
+    graph, built and placed on the mesh on first demand under the
+    ``mesh.partition`` span. Returns (partition, built in this call); the
+    partition is None for a model without a range test."""
+    key = (cfg, mesh, x.shape[0])
+    found = partitions.find(g, key)
+    if found is not None:
+        return found, False
+    if resolve_model(cfg.model).predicate is not fused_predicate:
+        return None, False
+    method = "fasst" if cfg.fasst else "naive"
+    with trace.span("mesh.partition", phase="plan", mu_v=mu_v,
+                    mu_s=mu_s) as sp:
+        gs = g.sorted_by_dst()
+        with trace.span("partition.sample_edge_sets", phase="plan",
+                        mu_s=mu_s, method=f"{method}-ranges"):
+            sampled = sample_edge_sets_in_ranges(
+                gs, x, mu_s, seed=cfg.seed, model=cfg.model, method=method)
+        if sampled is None:
+            return None, False
+        plan = plan_partition(gs, mu_v, mu_s=mu_s, strategy=cfg.partition,
+                              seed=cfg.seed, model=cfg.model, method=method,
+                              sampled=sampled)
+        part = build_partition_2d(gs, x, mu_v, mu_s, seed=cfg.seed,
+                                  method=method, model=cfg.model, plan=plan,
+                                  pad_mode=cfg.pad_mode, sampled=sampled)
+        sim_spec = _sim_spec(cfg.sim_axes)
+        bucket = NamedSharding(mesh, P(cfg.vertex_axis, sim_spec, None))
+        arrays = _bucket_arrays(part)
+        owned, buckets = sp.sync(jax.device_put(
+            (part.owned_ids, arrays),
+            (NamedSharding(mesh, P(cfg.vertex_axis, None)),
+             [bucket] * len(arrays))))
+        sp.annotate(bucket_slots=part.bucket_slots,
+                    bucket_pad_share=part.bucket_pad_share,
+                    ring_bytes_per_sweep=part.comm_bytes_per_sweep)
+    metrics.counter("mesh.partitions_built").inc()
+    entry = _MeshPartition(graph=gs, part=part, ranges=sampled.ranges,
+                           owned=owned, buckets=tuple(buckets))
+    partitions.add(g, key, entry)
+    return entry, True
+
+
+def _partition_and_args(g: Graph, mesh, cfg: DistributedConfig,
+                        x: np.ndarray, plan, mu_v: int, mu_s: int,
+                        partitions: Optional[PartitionCache], kind: tuple,
+                        make_program):
+    """The partition, the program and the arguments of one mesh call.
+
+    An X whose shards fall inside the fixed value ranges runs on the
+    graph's resident partition and its program of ``kind``, built once;
+    any other X (or a model without a range test) builds its own
+    partition and program for this call and is counted in
+    ``mesh.partition_fallbacks``. An explicit ``plan`` takes the per-X
+    build too, uncounted. Without ``partitions`` the fixed partition
+    serves this call alone. Returns (part, fn, args, job span flags)."""
+    method = "fasst" if cfg.fasst else "naive"
+    x_shards, _ = partition_samples(x, mu_s, method=method)
+    fixed, built = (None, False)
+    if plan is None:
+        fixed, built = _fixed_partition(g, mesh, cfg, x, mu_v, mu_s,
+                                        partitions or PartitionCache())
+    if fixed is not None and in_ranges(x_shards, fixed.ranges):
+        part = dataclasses.replace(fixed.part, x_shards=x_shards)
+        fn = fixed.program(kind, lambda: make_program(part))
+        args = [x_shards, fixed.owned, *fixed.buckets]
+        return part, fn, args, {"partition_reused": int(not built),
+                                "partition_fallback": 0}
+    if plan is None:
+        metrics.counter("mesh.partition_fallbacks").inc()
+    gs = fixed.graph if fixed is not None else g.sorted_by_dst()
+    part = _partition_for_x(gs, x, mu_v, mu_s, cfg, plan)
+    args = [part.x_shards, part.owned_ids, *_bucket_arrays(part)]
+    return part, make_program(part), args, {
+        "partition_reused": 0, "partition_fallback": int(plan is None)}
+
+
+def _job_program(part: Partition2D, k: int, mesh, cfg: DistributedConfig):
+    """The jitted shard_map of the full Alg. 4 loop on ``part``'s shapes."""
     maker = _make_distributed_fn(
         part, k=k, vertex_axis=cfg.vertex_axis, sim_axes=tuple(cfg.sim_axes),
         estimator=cfg.estimator, rebuild_threshold=cfg.rebuild_threshold,
@@ -394,31 +523,55 @@ def _run_distributed(g: Graph, k: int, mesh, cfg: DistributedConfig, x,
         seed=cfg.seed, schedule=cfg.schedule, local_sweeps=cfg.local_sweeps,
         fuse_sweeps=cfg.fuse_sweeps,
         predicate=resolve_model(cfg.model).predicate)
-    body = maker(mesh)
-
-    sim_spec = cfg.sim_axes if len(cfg.sim_axes) > 1 else cfg.sim_axes[0]
+    sim_spec = _sim_spec(cfg.sim_axes)
     bucket_spec = P(cfg.vertex_axis, sim_spec, None)
-    n_buckets = 10 * part.mu_v
-    in_specs = (P(sim_spec, None), P(cfg.vertex_axis, None)) + (bucket_spec,) * n_buckets
-    out_specs = (P(),) * 7
+    in_specs = ((P(sim_spec, None), P(cfg.vertex_axis, None))
+                + (bucket_spec,) * (10 * part.mu_v))
+    metrics.counter("mesh.programs_built").inc()
+    return jax.jit(jax.shard_map(
+        maker(mesh), mesh=mesh, in_specs=in_specs, out_specs=(P(),) * 7,
+        check_vma=False))
 
-    fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
-    # reshape x_shards so sim axes shard dim 0: (mu_s, j_loc)
-    args = [jnp.asarray(part.x_shards), jnp.asarray(part.owned_ids)]
-    for field in (part.p_h, part.p_w, part.p_r, part.p_t, part.p_l,
-                  part.c_h, part.c_w, part.c_r, part.c_t, part.c_l):
-        for step in field:
-            args.append(jnp.asarray(step))
-    t0 = perf_counter()
-    seeds, gains, scores, rebuilds, build_iters, casc, reb = jax.device_get(
-        fn(*args))
-    _publish_mesh_profile(part, phase="select", sweeps=int(build_iters),
-                          wall_s=perf_counter() - t0)
-    res = InfluenceResult(
-        seeds=seeds, est_gains=gains, scores=scores, rebuilds=rebuilds,
-        propagate_iters=int(build_iters), x=np.sort(x) if cfg.fasst else x,
-        cascade_iters=casc, rebuild_iters=reb)
+
+def _find_seeds_distributed(g: Graph, k: int, mesh,
+                            config: Optional[DistributedConfig] = None,
+                            x: Optional[np.ndarray] = None, plan=None,
+                            partitions: Optional[PartitionCache] = None):
+    """shard_map Alg. 4 driver (the ``mesh`` runtime backend's body).
+    Returns (InfluenceResult, Partition2D).
+
+    Seeds/estimates come back in original vertex ids for every
+    ``cfg.partition`` strategy (the relabeling is un-permuted on device via
+    ``owned_ids``). ``plan`` overrides the ``cfg.partition``-derived
+    :class:`PartitionPlan` (results are plan-invariant either way). The
+    ``mesh.find_seeds`` span covers the whole call, a partition built in it
+    included, and carries the job's sweep counters, compiles,
+    ``bucket_pad_share``, ``partition_reused`` and ``partition_fallback``.
+    """
+    cfg = config or DistributedConfig()
+    mu_v = mesh.shape[cfg.vertex_axis]
+    mu_s = math.prod(mesh.shape[ax] for ax in cfg.sim_axes)
+    compiles0 = metrics.counter("jit.compiles").value
+    with trace.span("mesh.find_seeds", phase="select", k=k, mu_v=mu_v,
+                    mu_s=mu_s, schedule=cfg.schedule) as sp:
+        if x is None:
+            x = make_x_vector(cfg.num_registers, seed=cfg.seed)
+        x = np.asarray(x, dtype=np.uint32)
+        part, fn, args, flags = _partition_and_args(
+            g, mesh, cfg, x, plan, mu_v, mu_s, partitions, ("job", k),
+            lambda p: _job_program(p, k, mesh, cfg))
+        t0 = perf_counter()
+        seeds, gains, scores, rebuilds, build_iters, casc, reb = (
+            jax.device_get(fn(*args)))
+        _publish_mesh_profile(part, phase="select", sweeps=int(build_iters),
+                              wall_s=perf_counter() - t0)
+        res = InfluenceResult(
+            seeds=seeds, est_gains=gains, scores=scores, rebuilds=rebuilds,
+            propagate_iters=int(build_iters),
+            x=np.sort(x) if cfg.fasst else x,
+            cascade_iters=casc, rebuild_iters=reb)
+        annotate_job(sp, res, compiles0,
+                     bucket_pad_share=part.bucket_pad_share, **flags)
     return res, part
 
 
@@ -550,21 +703,42 @@ def _make_build_matrix_fn(part: Partition2D, *, vertex_axis: str,
     return with_sizes
 
 
+def _build_program(part: Partition2D, mesh, cfg: DistributedConfig,
+                   reg_offset: int):
+    """The jitted shard_map of fill + propagate-to-fixpoint on ``part``'s
+    shapes; it takes the propagate half of :func:`_bucket_arrays`."""
+    maker = _make_build_matrix_fn(
+        part, vertex_axis=cfg.vertex_axis, sim_axes=tuple(cfg.sim_axes),
+        max_prop=cfg.max_propagate_iters, seed=cfg.seed, schedule=cfg.schedule,
+        local_sweeps=cfg.local_sweeps, fuse_sweeps=cfg.fuse_sweeps,
+        predicate=resolve_model(cfg.model).predicate, reg_offset=reg_offset)
+    sim_spec = _sim_spec(cfg.sim_axes)
+    bucket_spec = P(cfg.vertex_axis, sim_spec, None)
+    in_specs = ((P(sim_spec, None), P(cfg.vertex_axis, None))
+                + (bucket_spec,) * (5 * part.mu_v))
+    out_specs = (P(cfg.vertex_axis, sim_spec), P())
+    metrics.counter("mesh.programs_built").inc()
+    return jax.jit(jax.shard_map(
+        maker(mesh), mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False))
+
+
 def build_matrix_distributed(g: Graph, mesh,
                              config: Optional[DistributedConfig] = None,
                              x: Optional[np.ndarray] = None, *,
-                             reg_offset: int = 0, plan=None):
+                             reg_offset: int = 0, plan=None,
+                             partitions: Optional[PartitionCache] = None):
     """Alg. 4 lines 3-6 under shard_map: fill + propagate-to-fixpoint on the
     2-D partition, gathered back to the canonical layout.
 
     Expects ``g`` dst-sorted and ``x`` canonical (sorted when FASST) — the
-    normalized inputs the store/backend layer already holds. Returns
-    ``(matrix int8[g.n_pad, len(x)], iters, Partition2D)`` where ``matrix``
-    rows are in original-id order (the plan's relabeling is un-permuted on
-    host), bit-identical to the single-device ``build_sketch_matrix``.
+    normalized inputs the store/backend layer already holds. Runs on the
+    graph's resident partition and program where ``x`` falls inside its
+    value ranges, as the job does. Returns ``(matrix int8[g.n_pad,
+    len(x)], iters, Partition2D)`` where ``matrix`` rows are in
+    original-id order (the plan's relabeling is un-permuted on host),
+    bit-identical to the single-device ``build_sketch_matrix``.
     """
-    from jax.sharding import PartitionSpec as P
-
     cfg = config or DistributedConfig()
     mu_v = mesh.shape[cfg.vertex_axis]
     mu_s = math.prod(mesh.shape[ax] for ax in cfg.sim_axes)
@@ -573,37 +747,13 @@ def build_matrix_distributed(g: Graph, mesh,
         if cfg.fasst:
             x = np.sort(x)
     x = np.asarray(x, dtype=np.uint32)
-    sampled, method = _sample_edge_sets(g, x, mu_s, cfg)
-    if plan is None:
-        plan = plan_partition(g, mu_v, mu_s=mu_s, strategy=cfg.partition,
-                              seed=cfg.seed, model=cfg.model, method=method,
-                              sampled=sampled)
-    part = build_partition_2d(g, x, mu_v, mu_s, seed=cfg.seed, method=method,
-                              model=cfg.model, plan=plan, pad_mode=cfg.pad_mode,
-                              sampled=sampled)
-    maker = _make_build_matrix_fn(
-        part, vertex_axis=cfg.vertex_axis, sim_axes=tuple(cfg.sim_axes),
-        max_prop=cfg.max_propagate_iters, seed=cfg.seed, schedule=cfg.schedule,
-        local_sweeps=cfg.local_sweeps, fuse_sweeps=cfg.fuse_sweeps,
-        predicate=resolve_model(cfg.model).predicate, reg_offset=reg_offset)
-    body = maker(mesh)
-
-    sim_spec = cfg.sim_axes if len(cfg.sim_axes) > 1 else cfg.sim_axes[0]
-    bucket_spec = P(cfg.vertex_axis, sim_spec, None)
-    in_specs = ((P(sim_spec, None), P(cfg.vertex_axis, None))
-                + (bucket_spec,) * (5 * part.mu_v))
-    out_specs = (P(cfg.vertex_axis, sim_spec), P())
-    fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False))
-    args = [jnp.asarray(part.x_shards), jnp.asarray(part.owned_ids)]
-    for field in (part.p_h, part.p_w, part.p_r, part.p_t, part.p_l):
-        for step in field:
-            args.append(jnp.asarray(step))
+    part, fn, args, flags = _partition_and_args(
+        g, mesh, cfg, x, plan, mu_v, mu_s, partitions, ("build", reg_offset),
+        lambda p: _build_program(p, mesh, cfg, reg_offset))
     t0 = perf_counter()
     with trace.span("mesh.build_matrix", phase="build", mu_v=mu_v,
-                    mu_s=mu_s, reg_offset=reg_offset) as sp:
-        m_planned, iters = sp.sync(fn(*args))
+                    mu_s=mu_s, reg_offset=reg_offset, **flags) as sp:
+        m_planned, iters = sp.sync(fn(*args[:2 + 5 * mu_v]))
         # un-permute planned rows back to original-id (canonical) order
         m_canon = sp.sync(m_planned[jnp.asarray(part.plan.perm[: g.n_pad])])
     _publish_mesh_profile(part, phase="build", sweeps=int(iters),
@@ -660,8 +810,6 @@ def find_seeds_warm_distributed(g: Graph, k: int, mesh,
     cost, so repeat callers (the store's device TopKSeeds path) cache it
     against the entry version instead of paying it per query.
     """
-    from jax.sharding import PartitionSpec as P
-
     cfg = config or DistributedConfig()
     if part is None:
         part = _partition_for_plan(g, mesh, cfg, x, plan)
@@ -771,8 +919,6 @@ def repair_plan_shards_distributed(g: Graph, mesh,
     still sharded over the vertex axis, bit-identical to a full rebuild (and
     to the serial repair) by fixpoint uniqueness above a sound lower bound.
     """
-    from jax.sharding import PartitionSpec as P
-
     cfg = config or DistributedConfig()
     part = _partition_for_plan(g, mesh, cfg, x, plan)
     body = _make_repair_fn(

@@ -19,7 +19,8 @@ from repro.partition.builder import Partition2D, build_partition_2d
 from repro.partition.cost import PlanStats, measure_partition
 from repro.partition.plan import (PartitionPlan, SampledEdges,
                                   available_strategies, plan_partition,
-                                  register_strategy, sample_edge_sets)
+                                  register_strategy, sample_edge_sets,
+                                  sample_edge_sets_in_ranges)
 from repro.partition.serial import find_seeds_ring_serial
 
 __all__ = [
@@ -34,4 +35,5 @@ __all__ = [
     "plan_partition",
     "register_strategy",
     "sample_edge_sets",
+    "sample_edge_sets_in_ranges",
 ]

@@ -1,9 +1,16 @@
 """Host-side 2-D partition build: plan -> bucketed, padded device arrays.
 
-Moved out of ``core/distributed.py`` so the planning layer (``plan.py``) and
-the device runtime are decoupled: the builder consumes a
-:class:`~repro.partition.plan.PartitionPlan` (vertex relabeling) and emits
-the fixed-shape bucket arrays the ``shard_map`` body sweeps over. Two
+The builder consumes a :class:`~repro.partition.plan.PartitionPlan` (vertex
+relabeling) and each sim shard's edge set, and emits the fixed-shape bucket
+arrays the ``shard_map`` body sweeps over. The mesh path builds it once per
+(graph, config, mesh), from the edge sets of the sim shards' fixed value
+ranges (:func:`~repro.partition.plan.sample_edge_sets_in_ranges`), and
+keeps it on the devices for every job whose X falls inside the ranges. An
+X outside them, or a model without a range test (``lt``), takes the per-X
+build: the edge sets X's own FASST chunks sample
+(:func:`~repro.partition.plan.sample_edge_sets`), built for that call
+alone. A bucket may hold edges dead for all of its shard's registers: the
+per-(edge, register) predicate on the device makes them add nothing. Two
 padding modes:
 
   * ``"global"`` — every bucket padded to one global ``b_max`` (the
@@ -63,6 +70,18 @@ class Partition2D:
     comm_bytes_per_sweep: int  # ring traffic per device per sweep (both phases equal)
     plan: Optional[PartitionPlan] = None
     pad_mode: str = "step"
+
+    @property
+    def bucket_slots(self) -> int:
+        """Slots of the propagate and cascade buckets, over every ring step
+        and shard."""
+        return int(sum(a.size for a in self.p_h + self.c_h))
+
+    @property
+    def bucket_pad_share(self) -> float:
+        """Share of :attr:`bucket_slots` that hold no edge."""
+        real = int(self.p_counts.sum() + self.c_counts.sum())
+        return 1.0 - real / max(self.bucket_slots, 1)
 
     def stats(self):
         """Measured cost-model stats (see ``repro.partition.cost``)."""

@@ -27,9 +27,12 @@ Strategies (registry, pluggable like the diffusion model zoo):
   * ``random`` — seeded balanced random assignment (test/baseline aid:
                  results must be invariant under any relabeling).
 
-Vertex weights honor the sample space: when ``x`` is given, each edge counts
-once per sim shard whose FASST chunk samples it (exactly the multiplicity
-the bucket arrays will carry); without ``x`` every real edge counts once.
+Vertex weights honor the sample space: each edge counts once per sim shard
+whose edge set holds it (exactly the multiplicity the bucket arrays will
+carry): the sets of ``sampled`` where given (the mesh path passes the sets
+of its fixed value ranges, :func:`sample_edge_sets_in_ranges`, so the plan
+does not depend on X), else those of ``x``'s FASST chunks; without either
+every real edge counts once.
 """
 from __future__ import annotations
 
@@ -113,6 +116,9 @@ class SampledEdges:
     ep: object             # diffusion EdgeParams (h, lo, thr)
     x_shards: np.ndarray   # uint32[mu_s, j_loc]
     masks: tuple           # per sim shard: int64 indices of its sampled edges
+    # uint32[mu_s, 2] value ranges the masks cover (sample_edge_sets_in_ranges);
+    # None where the masks are those of x_shards alone
+    ranges: Optional[np.ndarray] = None
 
 
 def sample_edge_sets(g: Graph, x: np.ndarray, mu_s: int, *, seed: int = 0,
@@ -129,6 +135,35 @@ def sample_edge_sets(g: Graph, x: np.ndarray, mu_s: int, *, seed: int = 0,
                                    predicate=mdl.predicate))[0]
         for s in range(mu_s))
     return SampledEdges(ep=ep, x_shards=x_shards, masks=masks)
+
+
+def sample_edge_sets_in_ranges(g: Graph, x: np.ndarray, mu_s: int, *,
+                               seed: int = 0, model: str = "wc",
+                               method: str = "fasst"
+                               ) -> Optional[SampledEdges]:
+    """Each sim shard's edge set fixed by the graph: every edge live for
+    *some* value of the shard's :func:`~repro.core.fasst.shard_value_ranges`
+    range, a superset of what any X inside the ranges samples. ``x`` only
+    supplies the ``x_shards`` of the returned sets. None for a model whose
+    predicate has no range test (anything but the plain threshold compare
+    with ``lo = 0``: ``lt``'s remixed interval)."""
+    from repro.core.fasst import (live_in_range, partition_samples,
+                                  shard_value_ranges)
+    from repro.core.sampling import fused_predicate
+    from repro.diffusion import resolve as resolve_model
+
+    mdl = resolve_model(model)
+    if mdl.predicate is not fused_predicate:
+        return None
+    ep = mdl.edge_params(g, seed=seed)
+    if np.any(ep.lo):
+        return None
+    x = np.asarray(x, dtype=np.uint32)
+    x_shards, _ = partition_samples(x, mu_s, method=method)
+    ranges = shard_value_ranges(x.shape[0], mu_s, method=method)
+    masks = tuple(np.nonzero(live_in_range(ep.h, ep.thr, ranges[s]))[0]
+                  for s in range(mu_s))
+    return SampledEdges(ep=ep, x_shards=x_shards, masks=masks, ranges=ranges)
 
 
 def _edge_multiplicity(g: Graph, x: Optional[np.ndarray], mu_s: int, *,

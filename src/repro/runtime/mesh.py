@@ -7,6 +7,7 @@ same ring schedule — results are bit-identical by contract).
 """
 from __future__ import annotations
 
+import functools
 import math
 import time
 from typing import Optional
@@ -21,6 +22,15 @@ from repro.runtime.spec import RunSpec
 
 class MeshBackend(Backend):
     name = "mesh"
+
+    @functools.cached_property
+    def partitions(self):
+        """Each graph's partition, placed on its mesh, and the programs
+        built on its shapes: built by the first job or build, reused by
+        every later call whose x falls inside its value ranges."""
+        from repro.core.distributed import PartitionCache
+
+        return PartitionCache()
 
     def capabilities(self) -> BackendCapabilities:
         return BackendCapabilities(
@@ -72,7 +82,8 @@ class MeshBackend(Backend):
         # (local_sweeps, pad_mode) ranking transfers to the device path
         spec = apply_tuning(g, spec, self.name)
         cfg = spec.distributed_config()
-        res, part = _dist._find_seeds_distributed(g, k, mesh, cfg, x, plan=plan)
+        res, part = _dist._find_seeds_distributed(
+            g, k, mesh, cfg, x, plan=plan, partitions=self.partitions)
         return RunReport(result=res, backend=self.name, spec=spec,
                          partition=part, wall_s=time.perf_counter() - t0)
 
@@ -80,7 +91,9 @@ class MeshBackend(Backend):
                      reg_offset: int = 0, normalized: bool = False,
                      edges=None, mesh=None):
         # ``edges`` (single-backend device operands) is not applicable: the
-        # shard_map build re-buckets per x-slice on host.
+        # shard_map build sweeps the graph's partition, bucketed once and
+        # resident on the mesh (an x outside its value ranges buckets its
+        # own, for that call alone).
         self._check(g, spec)
         from repro.core import distributed as _dist
 
@@ -97,7 +110,8 @@ class MeshBackend(Backend):
                 f"bank of {np.asarray(x).shape[0]} registers not divisible "
                 f"by the mesh's {mu_s} sim shard(s)")
         m, iters, _ = _dist.build_matrix_distributed(
-            g, mesh, cfg, x, reg_offset=reg_offset)
+            g, mesh, cfg, x, reg_offset=reg_offset,
+            partitions=self.partitions)
         return m, iters
 
     # -- shard-level repair (device-resident store banks) ------------------

@@ -65,4 +65,22 @@ def test_all_meshes_match_single_device(dist_results):
 
 
 def test_fasst_balances_shards(dist_results):
+    """Each sim shard of the mesh's fixed partition holds at most 0.64 of the
+    arcs of the four-chip cell's graph (its generator at scale 16): with
+    J = 1024 and two sim shards, a shard's value range takes 5/8 of the
+    threshold-0.1 prefix classes."""
+    from bench import graphgen
+    from repro.core.sampling import make_x_vector
+    from repro.graphs.structs import Graph
+    from repro.partition import sample_edge_sets_in_ranges
+
     assert dist_results["ring_2x4"]["max_shard"] > 0
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench/configs/g500-s19-w1-hubids.json")) as f:
+        cfg = dict(json.load(f), scale=16)
+    e = graphgen.for_config(cfg)
+    g = Graph.from_edges(e.n, e.src, e.dst, e.weight).sorted_by_dst()
+    fixed = sample_edge_sets_in_ranges(g, make_x_vector(1024), 2)
+    shares = [len(m) / g.m_real for m in fixed.masks]
+    assert max(shares) <= 0.64, shares
+    assert min(shares) >= 0.5, shares

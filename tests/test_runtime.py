@@ -483,7 +483,7 @@ print(json.dumps({
 
 def test_mesh_body_counts_the_sweeps_single_does():
     """The shard_map body on four CPU devices returns the single driver's
-    counters, and its job span encloses the per-call partition."""
+    counters, and the first job's span encloses the partition it builds."""
     import json
     import os
     import subprocess
@@ -502,11 +502,17 @@ def test_mesh_body_counts_the_sweeps_single_does():
              for name, parent, sid, attrs in out["spans"]}
     job_parent, job_id, job_attrs = spans["mesh.find_seeds"]
     assert job_parent is None
+    part_parent, part_id, part_attrs = spans["mesh.partition"]
+    assert part_parent == job_id
     for name in ("partition.sample_edge_sets", "partition.plan",
                  "partition.build_buckets"):
-        assert spans[name][0] == job_id, name
+        assert spans[name][0] == part_id, name
+    assert part_attrs["bucket_slots"] > 0
     assert {k: job_attrs[k] for k in out["counts"]} == out["counts"]
     assert job_attrs["compiles"] >= 1
+    assert job_attrs["partition_reused"] == 0
+    assert job_attrs["partition_fallback"] == 0
+    assert job_attrs["bucket_pad_share"] == part_attrs["bucket_pad_share"]
 
 
 def test_job_span_carries_the_counters_and_compiles():
